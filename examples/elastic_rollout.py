@@ -12,10 +12,12 @@ import traceback
 from repro.configs import get_config
 from repro.core import ReferenceServer, TensorHubClient
 from repro.data.synthetic import PromptSet
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rl import RLConfig, RolloutWorker, TrainerWorker
 
 
 def main() -> None:
+    enable_compile_cache()
     model_cfg = get_config("llama3-8b").reduced()
     cfg = RLConfig(num_steps=6, prompt_len=6, response_len=10, num_prompts=2, group_size=2)
     server = ReferenceServer()

@@ -9,7 +9,8 @@ transfer).
 The reward is rule-based (valid bigram-chain continuations); mean reward
 rises as the policy learns the chain. Weight versions flow trainer ->
 rollouts via publish/update; the server stats at the end show the
-reference traffic.
+reference traffic. Rollout workers take JAX's devices in turn, and each
+lands its pulled weights on its own.
 """
 
 import argparse
@@ -18,13 +19,24 @@ import threading
 import time
 import traceback
 
+import jax
+
 from repro.configs import get_config
 from repro.core import ReferenceServer, TensorHubClient
 from repro.data.synthetic import PromptSet
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rl import RLConfig, RolloutWorker, TrainerWorker
 
 
-def main() -> None:
+def _raise_worker_errors(workers) -> None:
+    for w in workers:
+        if w.error:
+            traceback.print_exception(w.error)
+            raise SystemExit(1)
+
+
+def main(argv=None) -> list:
+    """Runs the loop; returns the trainer's per-step metrics."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--rollout-workers", type=int, default=2)
@@ -33,7 +45,8 @@ def main() -> None:
     ap.add_argument("--vocab", type=int, default=0)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt-dir", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     model_cfg = dataclasses.replace(get_config("llama3-8b").reduced(), vocab=128)
     if args.d_model:
@@ -56,8 +69,12 @@ def main() -> None:
     queue, stop = [], threading.Event()
 
     trainer = TrainerWorker(hub, cfg, model_cfg, queue)
+    devices = jax.devices()
     workers = [
-        RolloutWorker(f"rollout-{i}", hub, cfg, model_cfg, prompts, queue, stop)
+        RolloutWorker(
+            f"rollout-{i}", hub, cfg, model_cfg, prompts, queue, stop,
+            device=devices[i % len(devices)],
+        )
         for i in range(args.rollout_workers)
     ]
     for w in workers:
@@ -67,10 +84,7 @@ def main() -> None:
     try:
         for step in range(cfg.num_steps):
             rollouts = trainer.wait_for_rollouts(args.rollout_workers, timeout=600)
-            for w in workers:
-                if w.error:
-                    traceback.print_exception(w.error)
-                    raise SystemExit(1)
+            _raise_worker_errors(workers)
             m = trainer.train_on(rollouts)
             if step % 5 == 0 or step == cfg.num_steps - 1:
                 print(
@@ -86,6 +100,7 @@ def main() -> None:
         stop.set()
         for w in workers:
             w.join(timeout=120)
+    _raise_worker_errors(workers)
     trainer.close()
 
     first = trainer.metrics_log[0]["mean_reward"]
@@ -94,6 +109,7 @@ def main() -> None:
     print(f"\nreward: first {first:.3f} -> last-10 avg {avg_last:.3f}")
     print("server stats:", server.stats)
     print("rollout steps:", {w.name: w.steps_done for w in workers})
+    return trainer.metrics_log
 
 
 if __name__ == "__main__":

@@ -359,8 +359,9 @@ class ShardHandle:
         #: pulls, and the sub-unit chunk threshold (None = off)
         self.window = window
         self.chunk_bytes = chunk_bytes
-        #: repack staged reshard bytes through the Pallas gather kernel
-        #: (repro.kernels.repack) instead of the NumPy reference path
+        #: decode and repack reshard bytes on the JAX device
+        #: (repro.kernels.repack / quant.fused) instead of the NumPy
+        #: reference path
         self.device_repack = device_repack
         self.store = WorkerStore(worker.worker_id)
         self.current_version: Optional[int] = None
@@ -1770,7 +1771,8 @@ class ShardHandle:
             codec=codec,
         )
         executor = ReshardExecutor(
-            plan, local_manifest, use_kernel=self.device_repack
+            plan, local_manifest, use_kernel=self.device_repack,
+            recorder=self.client.recorder,
         )
         source = assignment.source
         rec = self.client.recorder

@@ -5,23 +5,17 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.checksum.kernel import checksum_words
+from repro.kernels.checksum.kernel import checksum_tensor
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def tensor_checksum(x: jax.Array, *, interpret: bool = False) -> jax.Array:
     """Returns uint32[2] = (s1, s2) over the tensor's little-endian bytes,
-    matching ``repro.transfer.checksum.checksum`` (fold64 combines them)."""
-    raw = jax.lax.bitcast_convert_type(x.reshape(-1), jnp.uint8).reshape(-1)
-    pad = (-raw.shape[0]) % 4
-    if pad:
-        raw = jnp.pad(raw, (0, pad))
-    b = raw.reshape(-1, 4).astype(jnp.uint32)
-    words = b[:, 0] | (b[:, 1] << 8) | (b[:, 2] << 16) | (b[:, 3] << 24)
-    return checksum_words(words, interpret=interpret)
+    matching ``repro.transfer.checksum.checksum`` (fold64 combines them).
+    The kernel reads the tensor in its own dtype and shape: no copy."""
+    return checksum_tensor(x, interpret=interpret)
 
 
 def host_equivalent(x) -> int:

@@ -2,26 +2,28 @@
 
 The staged reshard decode path materializes every interval twice: decode
 the int8 frame into a staging buffer, then repack (gather) staging bytes
-into the destination unit's layout. This module fuses the two — a single
-Pallas kernel reads the concatenated quantized values and per-row scales
-of *all* frames of one destination unit and writes dequantized elements
+into the destination unit's layout. This module fuses the two — one
+jitted device program reads the quantized rows and per-row scales of
+*all* frames of one destination unit and writes dequantized elements
 directly at their repacked positions:
 
-    out[i] = (q[qidx[i]] * scales[sidx[i]]).astype(out_dtype)
+    out[i] = (q[idx[i]] * scales[idx[i] // row_len]).astype(out_dtype)
 
-``qidx``/``sidx`` are precomputed int32 element maps (host-built from
-the plan's placements, like ``repack.build_gather_map`` but in element
-space); row-grid ``lead``/``tail`` widening is simply never mapped, so
-the trimmed bytes are dropped for free instead of decoded-then-discarded.
+``idx`` is an int32 element map, built on host from the plan's
+placements window by window (``repack.build_gather_map`` in element
+space); the row-grid ``lead``/``tail`` widening is never mapped, so the
+trimmed elements are never decoded.
 
 The kernel path requires every quantized frame of the unit to share one
 TPU-friendly element dtype (f32/bf16/f16) and element-aligned
 placements; anything else — mixed dtypes, f64, passthrough-only units —
 takes :func:`fused_repack_np`, the NumPy fusion of the same two passes
-(decode rows straight into the output span, no staging buffer). Both
+(decode rows straight into the output span, no staging buffer).
+``ReshardExecutor`` picks the path per unit and counts each choice in
+telemetry (``decode/kernel_units``, ``decode/host_units``). Both
 paths are bit-identical to staged decode-then-repack: the dequant math
 is exactly ``Int8Codec.decode``'s (f32 multiply, round-to-nearest-even
-downcast), and parity is pinned by tests in interpreter mode.
+downcast), and parity is pinned by tests.
 
 Frames arrive parsed (:func:`repro.transfer.codec.parse_int8_frame`), so
 header/scale/shape validation happened exactly once, at the transport
@@ -30,11 +32,15 @@ boundary.
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.meta import dtype_from_str
+from repro.kernels.repack.ops import Run, gather_windows
 
 #: dtypes the device kernel handles (min-tile-friendly; f64 stays on host)
 _KERNEL_DTYPES = ("float32", "bfloat16", "float16")
@@ -93,16 +99,18 @@ def fused_repack_np(
 def kernel_dtype(placements: Sequence[Placement], out_nbytes: int) -> Optional[str]:
     """The single element dtype the device kernel would run at, or
     ``None`` when this unit must take the NumPy path (mixed/unsupported
-    dtypes, element-misaligned placements, nothing quantized)."""
+    dtypes or row lengths, element-misaligned placements, nothing
+    quantized)."""
     dtype: Optional[str] = None
+    row_len: Optional[int] = None
     for frame, lead, nbytes, uo in placements:
         if frame.is_passthrough:
             continue
         if frame.dtype not in _KERNEL_DTYPES:
             return None
         if dtype is None:
-            dtype = frame.dtype
-        elif frame.dtype != dtype:
+            dtype, row_len = frame.dtype, frame.row_len
+        elif frame.dtype != dtype or frame.row_len != row_len:
             return None
         isz = dtype_from_str(dtype).itemsize
         if lead % isz or nbytes % isz or uo % isz:
@@ -112,119 +120,73 @@ def kernel_dtype(placements: Sequence[Placement], out_nbytes: int) -> Optional[s
     return dtype
 
 
-def build_elem_maps(
+def build_elem_map(
     placements: Sequence[Placement], out_nbytes: int, dtype: str
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Host-side map building for the device kernel: concatenate every
-    quantized frame's values/scales and map each output *element* to its
-    (q, scale) position. Returns ``(qcat, scat, qidx, sidx)``; uncovered
-    elements (gaps, passthrough spans overlaid later) point at the
-    appended sentinel pair (q=0, scale=1.0) and decode to 0.0."""
+) -> Tuple[np.ndarray, np.ndarray, List[Run], int, int]:
+    """Host-side inputs of the device kernel: lay every quantized frame's
+    values out on whole rows (a ragged last row is zero-padded), so
+    element ``i`` of ``qcat`` has the scale ``scat[i // row_len]``, and
+    describe each placement as an element run into the output. Returns
+    ``(qcat, scat, runs, fill, row_len)``; output elements no run covers
+    (gaps, passthrough spans overlaid later) map to ``fill``, the first
+    element of an appended all-zero row, and decode to 0.0."""
     isz = dtype_from_str(dtype).itemsize
-    n_elems = out_nbytes // isz
-    q_parts: List[np.ndarray] = []
-    s_parts: List[np.ndarray] = []
-    qidx = np.empty(n_elems, np.int32)
-    sidx = np.empty(n_elems, np.int32)
-    covered = np.zeros(n_elems, bool)
-    qoff = soff = 0
-    for frame, lead, nbytes, uo in placements:
-        if frame.is_passthrough or nbytes <= 0:
-            continue
-        oe0 = uo // isz
-        cnt = nbytes // isz
-        fe0 = lead // isz
-        span = fe0 + np.arange(cnt, dtype=np.int32)
-        qidx[oe0 : oe0 + cnt] = qoff + span
-        sidx[oe0 : oe0 + cnt] = soff + span // frame.row_len
-        covered[oe0 : oe0 + cnt] = True
-        q_parts.append(frame.q)
-        s_parts.append(frame.scales)
-        qoff += frame.q.size
-        soff += frame.scales.size
-    q_parts.append(np.zeros(1, np.int8))  # the sentinel pair
-    s_parts.append(np.ones(1, np.float32))
-    qidx[~covered] = qoff
-    sidx[~covered] = soff
-    return np.concatenate(q_parts), np.concatenate(s_parts), qidx, sidx
+    quantized = [
+        p for p in placements if not p[0].is_passthrough and p[2] > 0
+    ]
+    row_len = quantized[0][0].row_len
+    rows = sum(frame.scales.size for frame, _, _, _ in quantized)
+    if (rows + 1) * row_len > np.iinfo(np.int32).max:
+        raise ValueError(f"unit of {rows} rows overflows the int32 element map")
+    qcat = np.zeros((rows + 1) * row_len, np.int8)
+    scat = np.zeros(rows + 1, np.float32)
+    runs: List[Run] = []
+    r0 = 0
+    for frame, lead, nbytes, uo in quantized:
+        q0 = r0 * row_len
+        qcat[q0 : q0 + frame.q.size] = frame.q
+        scat[r0 : r0 + frame.scales.size] = frame.scales
+        runs.append((q0 + lead // isz, uo // isz, nbytes // isz))
+        r0 += frame.scales.size
+    return qcat, scat, runs, rows * row_len, row_len
 
 
-def _pad_to(arr: np.ndarray, multiple: int) -> np.ndarray:
-    pad = (-arr.shape[0]) % multiple
-    if pad:
-        arr = np.concatenate([arr, np.zeros(pad, arr.dtype)])
-    return arr
-
-
-def dequant_gather(
-    q, scales, qidx, sidx, out_dtype, *, interpret: bool = False
-):
-    """The fused Pallas kernel: ``out[i] = (q[qidx[i]] * scales[sidx[i]])
-    .astype(out_dtype)`` with q/scales fully in VMEM (one destination
-    unit's frames, bounded like the repack staging buffer) and output
-    element blocks streamed, mirroring ``repack.gather_bytes``."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    from repro.kernels.repack.kernel import _LANES, BLOCK_ROWS
-
-    def _kernel(qidx_ref, sidx_ref, q_ref, s_ref, out_ref):
-        qf = q_ref[...].reshape(-1)
-        sf = s_ref[...].reshape(-1)
-        vals = jnp.take(qf, qidx_ref[...], axis=0).astype(jnp.float32)
-        scale = jnp.take(sf, sidx_ref[...], axis=0)
-        out_ref[...] = (vals * scale).astype(out_ref.dtype)
-
-    n = qidx.shape[0]
-    block = BLOCK_ROWS * _LANES
-    pad = (-n) % block
-    qidx = jnp.asarray(qidx)
-    sidx = jnp.asarray(sidx)
-    if pad:
-        qidx = jnp.pad(qidx, (0, pad))  # index 0 is always valid
-        sidx = jnp.pad(sidx, (0, pad))
-    rows = qidx.shape[0] // _LANES
-    # int8 min tile is (32, 128), f32 (8, 128): pad the flat VMEM arrays
-    q2 = jnp.asarray(_pad_to(np.asarray(q), 32 * _LANES)).reshape(-1, _LANES)
-    s2 = jnp.asarray(_pad_to(np.asarray(scales), 8 * _LANES)).reshape(-1, _LANES)
-    out = pl.pallas_call(
-        _kernel,
-        grid=(rows // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((BLOCK_ROWS, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec((q2.shape[0], _LANES), lambda i: (0, 0)),
-            pl.BlockSpec((s2.shape[0], _LANES), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, _LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, _LANES), dtype_from_str(out_dtype)),
-        interpret=interpret,
-    )(
-        qidx.reshape(rows, _LANES),
-        sidx.reshape(rows, _LANES),
-        q2,
-        s2,
-    )
-    return out.reshape(-1)[:n]
+@functools.partial(jax.jit, static_argnames=("row_len", "out_dtype"))
+def dequant_gather(q, scales, idx, *, row_len: int, out_dtype: str):
+    """The fused device decode of one output window: ``out[i] =
+    (q[idx[i]] * scales[idx[i] // row_len]).astype(out_dtype)`` — the
+    same f32 multiply and round-to-nearest-even downcast as the NumPy
+    path. An XLA gather, not a Pallas kernel: Mosaic lowers only gathers
+    within one 2D block, and a unit's frames do not fit VMEM (see
+    ``repro.kernels.repack.kernel``)."""
+    vals = q.at[idx].get(mode="promise_in_bounds").astype(jnp.float32)
+    scale = scales.at[idx // row_len].get(mode="promise_in_bounds")
+    return (vals * scale).astype(dtype_from_str(out_dtype))
 
 
 def fused_repack(
-    placements: Sequence[Placement],
-    out_nbytes: int,
-    *,
-    interpret: bool = False,
+    placements: Sequence[Placement], out_nbytes: int
 ) -> np.ndarray:
-    """Device fused repack of one destination unit; falls back to
-    :func:`fused_repack_np` when the unit's frames aren't kernel-shaped
-    (mixed dtypes, f64, misalignment, passthrough-only)."""
+    """Device fused repack of one destination unit. The unit must be
+    kernel-shaped (:func:`kernel_dtype` is not ``None``); the caller
+    routes any other unit to :func:`fused_repack_np` and counts it."""
     dtype = kernel_dtype(placements, out_nbytes)
     if dtype is None:
-        return fused_repack_np(placements, out_nbytes)
-    qcat, scat, qidx, sidx = build_elem_maps(placements, out_nbytes, dtype)
-    dec = dequant_gather(qcat, scat, qidx, sidx, dtype, interpret=interpret)
-    # copy: device arrays view as read-only, and passthrough overlays write
-    out = np.asarray(dec).copy().view(np.uint8).reshape(-1)
+        raise ValueError(
+            "fused_repack: unit frames are not kernel-shaped (mixed or "
+            "unsupported dtypes, misaligned placements, nothing quantized)"
+        )
+    qcat, scat, runs, fill, row_len = build_elem_map(
+        placements, out_nbytes, dtype
+    )
+    q, s = jnp.asarray(qcat), jnp.asarray(scat)
+    out = np.empty(out_nbytes, np.uint8)
+    gather_windows(
+        runs,
+        out.view(dtype_from_str(dtype)),
+        fill,
+        lambda idx: dequant_gather(q, s, idx, row_len=row_len, out_dtype=dtype),
+    )
     # passthrough frames (non-finite payloads, odd tails) overlay their
     # exact bytes after the kernel — byte-granular, like the NumPy path
     for frame, lead, nbytes, uo in placements:
@@ -234,7 +196,7 @@ def fused_repack(
 
 
 __all__ = [
-    "build_elem_maps",
+    "build_elem_map",
     "dequant_gather",
     "fused_repack",
     "fused_repack_np",

@@ -15,14 +15,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.quant.ref import round_quotient
+
 _BLOCK_ROWS = 256
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)  # [bR, C]
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)  # [bR, 1]
-    scale = jnp.maximum(absmax / 127.0, 1e-12)
-    q = jnp.clip(jnp.round(x / scale), -127.0, 127.0)
+    scale = jnp.maximum(absmax * (1 / 127), 1e-12)  # as quantize_ref
+    q = jnp.clip(round_quotient(x, scale), -127.0, 127.0)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = jnp.broadcast_to(scale, s_ref.shape).astype(jnp.float32)
 

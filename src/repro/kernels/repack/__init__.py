@@ -2,12 +2,11 @@
 
 from repro.kernels.repack.kernel import gather_bytes
 from repro.kernels.repack.ops import build_gather_map, repack_bytes
-from repro.kernels.repack.ref import gather_ref, random_instructions, repack_ref
+from repro.kernels.repack.ref import random_instructions, repack_ref
 
 __all__ = [
     "build_gather_map",
     "gather_bytes",
-    "gather_ref",
     "random_instructions",
     "repack_bytes",
     "repack_ref",

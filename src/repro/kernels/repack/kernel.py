@@ -1,61 +1,34 @@
-"""Striped-read repack as a Pallas TPU gather kernel.
+"""Striped-read repack as a jitted device gather.
 
 Cross-layout resharding (``repro.resharding``) lands interval payloads
 from many source shards in a contiguous staging buffer; the repack step
 permutes those bytes into the destination transfer unit's layout. On the
-device this is a gather: a precomputed int32 index map (built on host
-from the plan's instructions, ``ops.build_gather_map``) maps every output
-byte to its staging position, and the kernel streams output blocks while
-the whole staging buffer sits in VMEM (staging is one transfer unit,
-bounded by the unit size / tiny-tensor bucket cap).
+device this is a gather: an int32 index map (built on host from the
+plan's instructions, ``ops.build_gather_map``) maps every output byte to
+its staging position. Byte granularity is the general case — intervals
+of bf16 tensors can land on 2-byte alignment, so a word-level gather
+cannot assume 4-byte-aligned runs.
 
-Blocks are (rows, 128) so the gather vectorizes across lanes; output
-positions past the real payload (block padding) index a guaranteed zero
-byte appended to staging. Byte-granularity gather is the general case —
-intervals of bf16 tensors can land on 2-byte alignment, so a word-level
-kernel cannot assume 4-byte-aligned runs.
+The gather is an XLA gather, not a Pallas kernel: Mosaic lowers only
+gathers within one 2D block along one axis (``tpu.dynamic_gather``), so
+an arbitrary byte permutation of a unit that does not fit VMEM has no
+Pallas form. XLA tiles the gather over HBM itself, and the same program
+runs on the CPU backend in tests. The output is produced in windows of
+``GATHER_WINDOW`` positions: a map of one int32 per output byte of a
+whole 1.88 GB unit would take 7.5 GB of HBM, and the TPU compiler copies
+it once more.
 """
 
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-_LANES = 128
-#: output rows per grid step (32 KiB of uint8 per block)
-BLOCK_ROWS = 256
+#: output positions per gather call: the index map of one call is 256 MiB
+GATHER_WINDOW = 64 << 20
 
 
-def _repack_kernel(idx_ref, staging_ref, out_ref):
-    flat = staging_ref[...].reshape(-1)  # full staging buffer in VMEM
-    out_ref[...] = jnp.take(flat, idx_ref[...], axis=0)
-
-
-def gather_bytes(
-    staging: jax.Array, idx: jax.Array, *, interpret: bool = False
-) -> jax.Array:
-    """staging: uint8[S] (S a multiple of 128), idx: int32[N] with every
-    entry in [0, S) -> uint8[N] = staging[idx]; N padded internally to a
-    block multiple (callers slice back)."""
-    n = idx.shape[0]
-    block = BLOCK_ROWS * _LANES
-    pad = (-n) % block
-    if pad:
-        idx = jnp.pad(idx, (0, pad))  # index 0 is always valid
-    rows = idx.shape[0] // _LANES
-    idx2d = idx.reshape(rows, _LANES)
-    s_rows = staging.shape[0] // _LANES
-
-    out = pl.pallas_call(
-        _repack_kernel,
-        grid=(rows // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((BLOCK_ROWS, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec((s_rows, _LANES), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, _LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.uint8),
-        interpret=interpret,
-    )(idx2d, staging.reshape(s_rows, _LANES))
-    return out.reshape(-1)[:n]
+@jax.jit
+def gather_bytes(staging: jax.Array, idx: jax.Array) -> jax.Array:
+    """staging: uint8[S], idx: int32[N] with every entry in [0, S) ->
+    uint8[N] = staging[idx]."""
+    return staging.at[idx].get(mode="promise_in_bounds")

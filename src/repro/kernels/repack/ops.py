@@ -1,54 +1,75 @@
-"""Host-facing repack entry points: gather-map building + kernel call."""
+"""Host-facing repack entry points: gather-map building + device gather."""
 
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Callable, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.repack.kernel import _LANES, gather_bytes
+from repro.kernels.repack.kernel import GATHER_WINDOW, gather_bytes
+
+#: a contiguous run ``(src_start, out_start, count)``: output positions
+#: ``[out_start, out_start + count)`` take source positions from
+#: ``src_start`` on
+Run = Tuple[int, int, int]
 
 
 def build_gather_map(
-    instructions: Sequence[Tuple[int, int, int]],
-    out_nbytes: int,
-    staging_nbytes: int,
+    runs: Sequence[Run], start: int, stop: int, fill: int
 ) -> np.ndarray:
-    """int32[out_nbytes] mapping every output byte to its staging byte.
-
-    Output bytes no instruction covers point at ``staging_nbytes`` — the
-    zero byte :func:`repack_bytes` appends — so they repack to 0, matching
-    the NumPy reference.
-    """
-    idx = np.full(out_nbytes, staging_nbytes, dtype=np.int32)
-    for s_off, d_off, nbytes in instructions:
-        if d_off < 0 or d_off + nbytes > out_nbytes:
-            raise ValueError(f"instruction out of range: {(s_off, d_off, nbytes)}")
-        if s_off < 0 or s_off + nbytes > staging_nbytes:
-            raise ValueError(f"staging read out of range: {(s_off, d_off, nbytes)}")
-        idx[d_off : d_off + nbytes] = np.arange(
-            s_off, s_off + nbytes, dtype=np.int32
-        )
+    """int32[stop - start] mapping output positions ``[start, stop)`` to
+    source positions; positions no run covers map to ``fill``."""
+    idx = np.full(stop - start, fill, dtype=np.int32)
+    for src, out, count in runs:
+        lo, hi = max(out, start), min(out + count, stop)
+        if lo < hi:
+            idx[lo - start : hi - start] = np.arange(
+                src + lo - out, src + hi - out, dtype=np.int32
+            )
     return idx
+
+
+def gather_windows(
+    runs: Sequence[Run],
+    out: np.ndarray,
+    fill: int,
+    gather: Callable[[jax.Array], jax.Array],
+) -> np.ndarray:
+    """Fill ``out`` (flat) window by window: ``gather(idx)`` runs on the
+    device for each window's index map. Every window has the same length
+    (the last one is padded with ``fill``), so one program serves them
+    all."""
+    n = out.shape[0]
+    w = min(GATHER_WINDOW, n)
+    for w0 in range(0, n, w):
+        w1 = min(w0 + w, n)
+        idx = build_gather_map(runs, w0, w0 + w, fill)
+        out[w0:w1] = np.asarray(gather(jnp.asarray(idx)))[: w1 - w0]
+    return out
 
 
 def repack_bytes(
     staging: np.ndarray,
-    instructions: Sequence[Tuple[int, int, int]],
+    instructions: Sequence[Run],
     out_nbytes: int,
-    *,
-    interpret: bool = False,
-) -> jnp.ndarray:
+) -> np.ndarray:
     """Device repack: assemble the destination unit payload (uint8
-    [out_nbytes]) from the staging buffer via the Pallas gather kernel."""
+    [out_nbytes]) from the staging buffer via the device gather. Output
+    bytes no instruction covers read the zero byte appended to staging,
+    matching the NumPy reference."""
     flat = np.asarray(staging, dtype=np.uint8).reshape(-1)
-    idx = build_gather_map(instructions, out_nbytes, flat.shape[0])
-    # append the zero byte uncovered positions index, then pad to lanes
-    padded = np.concatenate([flat, np.zeros(1, np.uint8)])
-    pad = (-padded.shape[0]) % _LANES
-    if pad:
-        padded = np.concatenate([padded, np.zeros(pad, np.uint8)])
-    return gather_bytes(
-        jnp.asarray(padded), jnp.asarray(idx), interpret=interpret
+    # the device gather reads out-of-range indices silently: refuse them
+    for s_off, d_off, nbytes in instructions:
+        if d_off < 0 or d_off + nbytes > out_nbytes:
+            raise ValueError(f"instruction out of range: {(s_off, d_off, nbytes)}")
+        if s_off < 0 or s_off + nbytes > flat.shape[0]:
+            raise ValueError(f"staging read out of range: {(s_off, d_off, nbytes)}")
+    dev = jnp.asarray(np.concatenate([flat, np.zeros(1, np.uint8)]))
+    return gather_windows(
+        instructions,
+        np.empty(out_nbytes, np.uint8),
+        flat.shape[0],
+        lambda idx: gather_bytes(dev, idx),
     )

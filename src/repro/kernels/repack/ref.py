@@ -1,17 +1,10 @@
-"""Pure-jnp / NumPy oracles for the repack gather kernel."""
+"""NumPy oracles for the repack gather kernel."""
 
 from __future__ import annotations
 
 from typing import List, Sequence, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-
-def gather_ref(staging: jax.Array, idx: jax.Array) -> jax.Array:
-    """staging: uint8[S], idx: int32[N] -> uint8[N] = staging[idx]."""
-    return jnp.take(staging, idx, axis=0)
 
 
 def repack_ref(

@@ -8,6 +8,11 @@ replicates it over the socketed data plane and prints a SHA-256 digest
 of its received bytes — all ranks printing the same digest is the
 demo's proof of byte-identical delivery.
 
+Every child does host-only work, so each starts with
+``JAX_PLATFORMS=cpu``: a child that reached JAX (the int8 codec jits its
+quantizer) would otherwise try to take the host's accelerator, which one
+process at a time may hold.
+
 This is the user-facing wrapper; the subprocess test tier
 (``tests/test_networked.py``) drives the same processes directly through
 ``tests/procs.py`` with kill/restart choreography on top.
@@ -91,12 +96,14 @@ def main(argv: Optional[list] = None) -> int:
     run_dir = args.run_dir or tempfile.mkdtemp(prefix="tensorhub-net-")
     addr_file = os.path.join(run_dir, "controller.addr")
     wal = os.path.join(run_dir, "controller.wal")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     controller = subprocess.Popen(
         [
             sys.executable, "-m", "repro.net.controller",
             "--addr-file", addr_file, "--wal", wal,
             "--heartbeat-timeout", str(args.heartbeat_timeout),
         ],
+        env=env,
     )
     print(f"controller pid={controller.pid} run_dir={run_dir}", flush=True)
     workers = []
@@ -107,10 +114,12 @@ def main(argv: Optional[list] = None) -> int:
             "--tensors", str(args.tensors), "--dim", str(args.dim),
             "--linger", str(args.linger),
         ]
-        workers.append(subprocess.Popen(common + ["--rank", "0"]))
+        workers.append(subprocess.Popen(common + ["--rank", "0"], env=env))
         time.sleep(0.5)  # let the publish land before readers race it
         for rank in range(1, args.workers):
-            workers.append(subprocess.Popen(common + ["--rank", str(rank)]))
+            workers.append(
+                subprocess.Popen(common + ["--rank", str(rank)], env=env)
+            )
         rc = 0
         for w in workers[1:]:
             rc |= w.wait()

@@ -38,6 +38,12 @@ CTR_DECODE = "stall/decode"
 CTR_VERIFY = "stall/verify"
 CTR_CONTROL = "stall/control"
 
+# Fused reshard decodes, one per destination unit, by the path that
+# decoded it: the device kernel, or the NumPy fusion (kernel not
+# requested, or the unit's frames are not kernel-shaped).
+CTR_DECODE_KERNEL_UNITS = "decode/kernel_units"
+CTR_DECODE_HOST_UNITS = "decode/host_units"
+
 # Gray-failure self-healing counters (event counts, not seconds): each
 # increment pairs with a span event of the same name carrying the
 # source/unit involved.

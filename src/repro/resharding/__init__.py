@@ -56,7 +56,7 @@ Execution
 intervals are pulled into a contiguous staging buffer (the RDMA-landing
 analogue), and once a destination transfer unit's intervals are all in,
 a *repack* step scatters staging bytes into the registered weight
-buffers — either the NumPy reference path or the Pallas gather kernel in
+buffers — either the NumPy reference path or the device gather in
 ``repro.kernels.repack``. Progress is counted in completed destination
 units, so a resharded replica serves its prefix to downstream readers
 exactly like a same-layout one (4.3.3 pipeline replication), and source

@@ -9,8 +9,10 @@ ordinary ``write_unit`` path, so downstream machinery (progress counters,
 pipelined readers, compact buckets) is unchanged.
 
 Repack runs either as a NumPy scatter (the reference path the threaded
-client uses by default) or through the Pallas gather kernel in
-``repro.kernels.repack`` (``use_kernel=True``; parity is tested).
+client uses by default) or through the device gather in
+``repro.kernels.repack`` (``use_kernel=True``; parity is tested). The
+device programs are jitted XLA, so they run compiled on whatever backend
+JAX holds: the TPU on the chip, the CPU in tests.
 """
 
 from __future__ import annotations
@@ -22,6 +24,7 @@ import numpy as np
 
 from repro.core.errors import TensorHubError
 from repro.core.meta import ShardManifest, TransferUnit
+from repro.obs import telemetry as obs
 from repro.resharding.planner import ReadInterval, ShardPlan
 
 
@@ -44,14 +47,14 @@ class ReshardExecutor:
         dest_manifest: ShardManifest,
         *,
         use_kernel: bool = False,
-        interpret: Optional[bool] = None,
+        recorder: Optional[obs.Recorder] = None,
     ) -> None:
         self.plan = plan
         self.manifest = dest_manifest
         self.use_kernel = use_kernel
-        #: None = auto: compiled on TPU, Pallas interpreter elsewhere
-        #: (CPU/GPU backends cannot compile the TPU gather kernel)
-        self.interpret = interpret
+        #: counts fused decodes per path (``decode/kernel_units`` and
+        #: ``decode/host_units``)
+        self.recorder = obs.DISABLED if recorder is None else recorder
         self._units: Dict[int, List[PlacedInterval]] = {}
         self._staging_bytes: Dict[int, int] = {}
         by_unit = plan.intervals_by_unit()
@@ -117,16 +120,9 @@ class ReshardExecutor:
         unit = self.manifest.units[dest_unit]
         instrs = self.instructions(dest_unit)
         if self.use_kernel:
-            import jax
-
             from repro.kernels.repack import repack_bytes
 
-            interpret = self.interpret
-            if interpret is None:
-                interpret = jax.default_backend() != "tpu"
-            return np.asarray(
-                repack_bytes(staging, instrs, unit.nbytes, interpret=interpret)
-            )
+            return np.asarray(repack_bytes(staging, instrs, unit.nbytes))
         return repack_np(staging, instrs, unit.nbytes)
 
     def fused_repack(
@@ -138,9 +134,11 @@ class ReshardExecutor:
         staging-buffer decode, and the row-grid ``lead``/``tail``
         widening is dropped instead of decoded-then-discarded.
 
-        ``use_kernel`` dispatches exactly like :meth:`repack`: the Pallas
-        kernel on device (or interpreter), the NumPy fusion otherwise.
-        Both are bit-identical to decode-then-:meth:`repack`.
+        ``use_kernel`` dispatches like :meth:`repack`: the device kernel
+        for every kernel-shaped unit, the NumPy fusion otherwise. Each
+        unit adds one to the recorder's ``decode/kernel_units`` or
+        ``decode/host_units``, so a unit that misses the kernel shows.
+        Both paths are bit-identical to decode-then-:meth:`repack`.
         """
         from repro.kernels.quant import fused as fused_lib
         from repro.transfer.codec import parse_int8_frame
@@ -163,15 +161,10 @@ class ReshardExecutor:
                     f"{iv.src_stop}] read {iv.read_nbytes}B"
                 )
             placements.append((frame, iv.lead, iv.nbytes, p.unit_offset))
-        if self.use_kernel:
-            import jax
-
-            interpret = self.interpret
-            if interpret is None:
-                interpret = jax.default_backend() != "tpu"
-            return fused_lib.fused_repack(
-                placements, unit.nbytes, interpret=interpret
-            )
+        if self.use_kernel and fused_lib.kernel_dtype(placements, unit.nbytes):
+            self.recorder.counter_add(obs.CTR_DECODE_KERNEL_UNITS, 1)
+            return fused_lib.fused_repack(placements, unit.nbytes)
+        self.recorder.counter_add(obs.CTR_DECODE_HOST_UNITS, 1)
         return fused_lib.fused_repack_np(placements, unit.nbytes)
 
 

@@ -93,6 +93,7 @@ class RolloutWorker(threading.Thread):
         *,
         datacenter: str = "dc0",
         is_spot: bool = False,
+        device: Optional[jax.Device] = None,
     ) -> None:
         super().__init__(name=name, daemon=True)
         self.hub = hub
@@ -105,6 +106,9 @@ class RolloutWorker(threading.Thread):
         self.datacenter = datacenter
         self.is_spot = is_spot
         self.replica_name = name
+        #: the device pulled weights land on and generation runs on
+        #: (None: JAX's default device)
+        self.device = device
         self.steps_done = 0
         self.weights_version: Optional[int] = None
         self.error: Optional[BaseException] = None
@@ -132,8 +136,9 @@ class RolloutWorker(threading.Thread):
         rollout_step = 0
         while not self.stop_event.is_set():
             params = self._params_from_buffers(params, buffers)
-            prompts = jnp.asarray(
-                self.prompts.sample(cfg.num_prompts * cfg.group_size, rollout_step)
+            prompts = jax.device_put(
+                self.prompts.sample(cfg.num_prompts * cfg.group_size, rollout_step),
+                self.device,
             )
             key = jax.random.PRNGKey(hash((self.replica_name, rollout_step)) % (2**31))
             seqs, lps = sample_responses(self.model, params, prompts, cfg.response_len, key)
@@ -160,7 +165,7 @@ class RolloutWorker(threading.Thread):
         flat = named_tensors(params)
         return jax.tree.unflatten(
             jax.tree.structure(params),
-            [jnp.asarray(buffers[k]) for k in flat],
+            [jax.device_put(buffers[k], self.device) for k in flat],
         )
 
 

@@ -26,7 +26,12 @@ from __future__ import annotations
 
 import numpy as np
 
-_MASK32 = np.uint64(0xFFFFFFFF)
+#: words folded per pass. The fold's uint64 temporaries stay a few MiB
+#: whatever the buffer (a whole-buffer pass would hold 8 bytes of them
+#: per payload byte: 15 GB for a 1.88 GB unit). A multiple of 65536, so
+#: the position weights repeat block by block.
+_BLOCK_WORDS = 1 << 20
+_WEIGHTS = (np.arange(_BLOCK_WORDS, dtype=np.uint64) & np.uint64(0xFFFF)) + np.uint64(1)
 
 #: stand-in for a non-empty buffer folding to exactly 0 — any fixed
 #: non-zero value works (the induced collision class is the same
@@ -47,14 +52,16 @@ def _as_words(buf: bytes | bytearray | memoryview | np.ndarray) -> np.ndarray:
 
 def checksum(buf: bytes | bytearray | memoryview | np.ndarray) -> int:
     """64-bit fold checksum of a byte buffer (see module docstring)."""
-    words = _as_words(buf).astype(np.uint64)
-    n = words.size
-    if n == 0:
+    words = _as_words(buf)
+    if words.size == 0:
         return 0
-    idx = np.arange(n, dtype=np.uint64)
-    weights = (idx & np.uint64(0xFFFF)) + np.uint64(1)
-    s1 = int(words.sum() & _MASK32)
-    s2 = int((words * weights).sum() & _MASK32)
+    s1 = s2 = 0
+    for b0 in range(0, words.size, _BLOCK_WORDS):
+        w = words[b0 : b0 + _BLOCK_WORDS].astype(np.uint64)
+        # uint64 sums wrap mod 2^64, which keeps them exact mod 2^32
+        s1 += int(w.sum(dtype=np.uint64))
+        s2 += int((w * _WEIGHTS[: w.size]).sum(dtype=np.uint64))
+    s1, s2 = s1 & 0xFFFFFFFF, s2 & 0xFFFFFFFF
     return ((s2 << 32) | s1) or ZERO_STANDIN
 
 

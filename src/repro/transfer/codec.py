@@ -6,8 +6,8 @@ transfer-unit payload into *wire bytes* at the source and back into
 weight bytes at the destination. The reference server negotiates the
 codec **per link class** when it builds an :class:`~repro.core.meta.Assignment`:
 WAN-crossing slices default to ``int8`` (symmetric per-row quantization,
-backed by the Pallas kernel package ``repro.kernels.quant``, with a
-pure-NumPy implementation when JAX is absent), intra-DC slices stay
+jitted from ``repro.kernels.quant`` onto JAX's default device, with a
+pure-NumPy reference that encodes the same bits), intra-DC slices stay
 ``raw``. The negotiated name travels on ``SourceSlice.codec`` /
 ``Assignment.codec`` and is honored by both data planes
 (``repro.transfer.engine`` for real bytes, ``repro.transfer.simcluster``
@@ -197,45 +197,45 @@ class Int8Codec(WireCodec):
     name = "int8"
     lossless = False
 
-    def __init__(self, row_len: int = INT8_ROW_LEN, backend: str = "auto") -> None:
+    def __init__(self, row_len: int = INT8_ROW_LEN, backend: str = "jax") -> None:
         if row_len <= 0:
             raise ValueError("row_len must be positive")
         self.row_len = row_len
-        if backend not in ("auto", "numpy", "jax"):
+        if backend not in ("numpy", "jax"):
             raise ValueError(f"unknown int8 backend {backend!r}")
         self._backend = backend
-        self._jax_quant = None  # resolved lazily
+        self._jax_quant = None  # jitted lazily: importing JAX is not free
 
     # -- backends ---------------------------------------------------------
 
     def _quant_rows(self, rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """f32 [R, L] -> (q int8 [R, L], scales f32 [R]). The jax path is
-        the ``kernels/quant`` oracle (jitted; numerically identical to the
-        Pallas kernel); NumPy reproduces it op-for-op (same IEEE ops, same
-        round-half-to-even), so mixed deployments stay deterministic."""
-        if self._backend != "numpy":
-            fn = self._resolve_jax()
-            if fn is not None:
-                q, s = fn(rows)
-                return np.asarray(q), np.asarray(s)
-            if self._backend == "jax":
-                raise CodecError("int8 codec: backend='jax' but JAX is unavailable")
-        absmax = np.max(np.abs(rows), axis=1)
-        scales = np.maximum(absmax / 127.0, 1e-12).astype(np.float32)
-        q = np.clip(np.rint(rows / scales[:, None]), -127, 127).astype(np.int8)
-        return q, scales
-
-    def _resolve_jax(self):
-        if self._jax_quant is None:
-            try:
+        ``kernels.quant.ref.quantize_ref``, jitted onto JAX's default
+        device; NumPy, the reference, reproduces it op for op (see
+        ``round_quotient`` there for why each op is exact), so every
+        backend encodes the same bits."""
+        if self._backend == "jax":
+            if self._jax_quant is None:
                 import jax
 
                 from repro.kernels.quant.ref import quantize_ref
 
                 self._jax_quant = jax.jit(quantize_ref)
-            except Exception:  # noqa: BLE001 — any import/backend failure
-                self._jax_quant = False
-        return self._jax_quant or None
+            q, s = self._jax_quant(rows)
+            return np.asarray(q), np.asarray(s)
+        absmax = np.max(np.abs(rows), axis=1)
+        scales = np.maximum(absmax * np.float32(1 / 127), np.float32(1e-12))
+        s = scales[:, None]
+        q = np.rint(rows / s)
+        hi = (s.view(np.int32) & np.int32(-4096)).view(np.float32)
+        lo = s - hi
+        for half in (np.float32(0.5), np.float32(-0.5)):
+            t = q + half
+            a, b = rows - t * hi, t * lo
+            past = a > b if half > 0 else a < b
+            odd = (q.astype(np.int32) & 1) != 0
+            q = np.where(past | ((a == b) & odd), q + 2 * half, q)
+        return np.clip(q, -127, 127).astype(np.int8), scales
 
     # -- framing ----------------------------------------------------------
 

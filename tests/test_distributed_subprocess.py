@@ -23,10 +23,12 @@ def test_shardmap_moe_matches_oracle_on_8_devices():
         """
         import dataclasses
         import jax, jax.numpy as jnp
+        from jax.sharding import AxisType
+        Auto = AxisType.Auto
         from repro.configs import get_config
         from repro.models import blocks, build_model, optim
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(Auto, Auto))
         cfg = get_config("dbrx-132b").reduced()
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=float(cfg.moe.num_experts)))
@@ -51,6 +53,8 @@ def test_train_step_numerics_invariant_to_sharding():
     out = run_py(
         """
         import jax, jax.numpy as jnp
+        from jax.sharding import AxisType
+        Auto = AxisType.Auto
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec
         from repro.configs import get_config
@@ -68,7 +72,7 @@ def test_train_step_numerics_invariant_to_sharding():
 
         p1, _, m1 = jax.jit(step)(params, state, {"tokens": toks})
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(Auto, Auto))
         psh = tree_shardings(model.param_specs(), TRAIN_RULES, mesh)
         with mesh:
             p2, _, m2 = jax.jit(step, in_shardings=(psh, None, None))(
@@ -91,6 +95,8 @@ def test_h1_constraint_preserves_numerics():
     out = run_py(
         """
         import jax, jax.numpy as jnp
+        from jax.sharding import AxisType
+        Auto = AxisType.Auto
         import numpy as np
         from repro.configs import get_config
         from repro.models import build_model, optim
@@ -100,7 +106,7 @@ def test_h1_constraint_preserves_numerics():
         params = model.init(jax.random.PRNGKey(0), jnp.float32)
         toks = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, cfg.vocab)
         base = model.forward(params, {"tokens": toks})
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(Auto, Auto))
         with mesh, optim.optimizations(mesh=mesh, shard_attn_heads=True):
             opt_out = jax.jit(lambda p, t: model.forward(p, {"tokens": t}))(params, toks)
         np.testing.assert_allclose(np.asarray(base), np.asarray(opt_out), rtol=2e-4, atol=2e-4)

@@ -69,11 +69,14 @@ class TestChecksum:
             ((65536,), jnp.float32),
             ((1,), jnp.float32),
             ((100001,), jnp.int32),
+            ((20, 3, 256), jnp.bfloat16),  # in place, 2-D grid
+            ((9, 1152), jnp.float32),  # rows padded to a sublane multiple
+            ((13, 384), jnp.int8),  # four elements per word
         ],
     )
     def test_kernel_matches_host(self, shape, dtype):
-        if dtype == jnp.int32:
-            x = jnp.arange(np.prod(shape), dtype=dtype).reshape(shape)
+        if dtype in (jnp.int32, jnp.int8):
+            x = jnp.arange(np.prod(shape), dtype=jnp.int32).astype(dtype).reshape(shape)
         else:
             x = jax.random.normal(jax.random.PRNGKey(1), shape).astype(dtype)
         got = fold64(np.asarray(tensor_checksum(x, interpret=True)))

@@ -152,9 +152,9 @@ def _frames(rng, specs):
 
 
 class TestFusedParity:
-    @pytest.mark.parametrize("interpret_kernel", [False, True])
-    def test_fused_matches_staged_decode(self, interpret_kernel):
-        """Fused placement decode (numpy + interpreter kernel) is
+    @pytest.mark.parametrize("device_kernel", [False, True])
+    def test_fused_matches_staged_decode(self, device_kernel):
+        """Fused placement decode (numpy + device kernel) is
         bit-identical to decode-whole-frame-then-trim — including
         lead/tail trimming and a passthrough overlay."""
         from repro.kernels.quant import fused_repack, fused_repack_np
@@ -180,9 +180,27 @@ class TestFusedParity:
         want[8192 : 8192 + 1000] = bad.view(np.uint8)[4 : 4 + 1000]
         got_np = fused_repack_np(placements, out_nbytes)
         assert np.array_equal(got_np, want)
-        if interpret_kernel:
-            got_k = fused_repack(placements, out_nbytes, interpret=True)
+        if device_kernel:
+            got_k = fused_repack(placements, out_nbytes)
             assert np.array_equal(got_k, want)
+
+    @pytest.mark.parametrize("window", [1000, 2048])
+    def test_windowed_fused_decode_matches_numpy(self, monkeypatch, window):
+        """A unit longer than one gather window decodes window by window
+        (the last one padded) to the NumPy fusion's bytes, gaps included."""
+        from repro.kernels.quant import fused_repack, fused_repack_np
+        from repro.kernels.repack import ops
+
+        monkeypatch.setattr(ops, "GATHER_WINDOW", window)
+        frames, _ = _frames(np.random.default_rng(window), [1024, 2048, 512])
+        placements = [
+            (frames[0], 0, 4096, 0),
+            (frames[1], RB, 4096, 4096 + 512),  # a 512-byte gap before it
+            (frames[2], 4, 1000, 9216),  # part of one row
+        ]
+        out_nbytes = 9216 + 1000
+        want = fused_repack_np(placements, out_nbytes)
+        assert np.array_equal(fused_repack(placements, out_nbytes), want)
 
     def test_executor_fused_repack_matches_staged(self):
         """ReshardExecutor.fused_repack over a real plan's wire frames ==
@@ -323,14 +341,24 @@ class TestEndToEndInt8Reshard:
 
     def test_fused_kernel_path_matches_numpy_path(self):
         """device_repack=True routes the resharded decode through the
-        fused Pallas kernel (interpreter off-TPU) — same bytes as the
-        NumPy fusion."""
+        fused device kernel — same bytes as the NumPy fusion."""
+        from repro import obs
+        from repro.obs import telemetry
+
         glob = _model_tensors()
-        hub = TensorHubClient(ReferenceServer())
+        rec = obs.Recorder()
+        hub = TensorHubClient(ReferenceServer(), recorder=rec)
         pubs = _open_tp_group(hub, "pub", 4, glob, dc="dc0")
         _run_group(pubs, lambda h: h.publish(0))
         subs_np = _open_tp_group(hub, "np", 2, glob, zeros=True, dc="dc1")
         _run_group(subs_np, lambda h: h.replicate(0))
+        host_units = rec.counter(telemetry.CTR_DECODE_HOST_UNITS)
+        assert host_units > 0
+        assert rec.counter(telemetry.CTR_DECODE_KERNEL_UNITS) == 0
+        # closed, so the kernel replica cannot copy its same-layout bytes
+        # and must reshard from the publisher
+        for h in subs_np:
+            h.close()
         subs_k = [
             hub.open("m", "kern", 2, i, datacenter="dc1", device_repack=True)
             for i in range(2)
@@ -341,6 +369,9 @@ class TestEndToEndInt8Reshard:
                 {n: np.zeros_like(a) for n, a in local.items()}, layout=lay
             )
         _run_group(subs_k, lambda h: h.replicate(0))
+        # every unit of the kernel pull decoded on the device, none on host
+        assert rec.counter(telemetry.CTR_DECODE_KERNEL_UNITS) > 0
+        assert rec.counter(telemetry.CTR_DECODE_HOST_UNITS) == host_units
         for ha, hb in zip(subs_k, subs_np):
             for n in glob:
                 assert np.array_equal(

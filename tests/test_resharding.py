@@ -222,7 +222,7 @@ class TestEndToEndReshard:
         assert all(h.intervals_pulled > 0 for h in subs)
 
     def test_device_repack_path(self):
-        """Pallas-kernel repack produces the same bytes as the NumPy path."""
+        """Device-gather repack produces the same bytes as the NumPy path."""
         glob = model_tensors(seed=3)
         hub = TensorHubClient(ReferenceServer())
         pubs = open_tp_group(hub, "pub", 4, glob)
@@ -339,19 +339,38 @@ class TestRepackKernel:
         staging = rng.integers(
             0, 256, sum(n for _, _, n in instrs), dtype=np.uint8
         )
-        got = np.asarray(repack_bytes(staging, instrs, out_nbytes, interpret=True))
+        got = np.asarray(repack_bytes(staging, instrs, out_nbytes))
+        np.testing.assert_array_equal(got, repack_ref(staging, instrs, out_nbytes))
+
+    @pytest.mark.parametrize("window", [777, 4096])
+    def test_windowed_repack_matches_ref(self, monkeypatch, window):
+        """An output longer than one gather window is gathered window by
+        window, the last one padded; the bytes are the same."""
+        from repro.kernels.repack import (
+            ops,
+            random_instructions,
+            repack_bytes,
+            repack_ref,
+        )
+
+        monkeypatch.setattr(ops, "GATHER_WINDOW", window)
+        rng = np.random.default_rng(window)
+        out_nbytes = 5000
+        instrs = random_instructions(rng, out_nbytes)
+        staging = rng.integers(
+            0, 256, sum(n for _, _, n in instrs), dtype=np.uint8
+        )
+        got = repack_bytes(staging, instrs, out_nbytes)
         np.testing.assert_array_equal(got, repack_ref(staging, instrs, out_nbytes))
 
     def test_gather_ref_matches_kernel(self):
-        from repro.kernels.repack import gather_bytes, gather_ref
-        import jax.numpy as jnp
+        from repro.kernels.repack import gather_bytes
 
         rng = np.random.default_rng(0)
-        staging = jnp.asarray(rng.integers(0, 256, 1024, dtype=np.uint8))
-        idx = jnp.asarray(rng.integers(0, 1024, 3000, dtype=np.int32))
+        staging = rng.integers(0, 256, 1024, dtype=np.uint8)
+        idx = rng.integers(0, 1024, 3000, dtype=np.int32)
         np.testing.assert_array_equal(
-            np.asarray(gather_bytes(staging, idx, interpret=True)),
-            np.asarray(gather_ref(staging, idx)),
+            np.asarray(gather_bytes(staging, idx)), staging[idx]
         )
 
     def test_executor_kernel_vs_numpy(self):

@@ -144,3 +144,39 @@ def test_publish_next_version_during_swarm_pull_no_torn_reads():
         _expect_version(r, 2)
     for r in rollouts + [mirror, trainer]:
         r.close()
+
+
+@pytest.mark.timeout(300)
+def test_rollout_lands_weights_on_its_device():
+    """A rollout given a device lands pulled weights there, not on JAX's
+    default device (two virtual CPU devices in a fresh interpreter)."""
+    from procs import run_py
+
+    out = run_py(
+        """
+        import threading
+        import jax, jax.numpy as jnp
+        import numpy as np
+        from repro.configs import get_config
+        from repro.core import ReferenceServer, TensorHubClient
+        from repro.data.synthetic import PromptSet
+        from repro.models import named_tensors
+        from repro.rl import RLConfig, RolloutWorker
+
+        cfg = get_config("llama3-8b").reduced()
+        target = jax.devices()[1]
+        w = RolloutWorker(
+            "r", TensorHubClient(ReferenceServer()), RLConfig(), cfg,
+            PromptSet(vocab=cfg.vocab, prompt_len=8), [], threading.Event(),
+            device=target,
+        )
+        params = w.model.init(jax.random.PRNGKey(0), jnp.float32)
+        bufs = {k: np.asarray(v) for k, v in named_tensors(params).items()}
+        landed = w._params_from_buffers(params, bufs)
+        on = {d for leaf in jax.tree.leaves(landed) for d in leaf.devices()}
+        assert on == {target}, on
+        print("LANDED_ON", target.id)
+        """,
+        devices=2,
+    )
+    assert "LANDED_ON 1" in out

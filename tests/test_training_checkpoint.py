@@ -10,15 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import AbstractMesh, PartitionSpec
 
-
-def make_abstract_mesh(shape, names):
-    """AbstractMesh across JAX API generations: newer versions take a
-    ``((name, size), ...)`` shape tuple, older ones ``(shape, names)``."""
-    try:
-        return AbstractMesh(tuple(zip(names, shape)))
-    except TypeError:
-        return AbstractMesh(shape, names)
-
 from repro import checkpoint as ckpt
 from repro.configs import get_config
 from repro.data.synthetic import BigramStream, PromptSet
@@ -161,7 +152,7 @@ class TestCheckpoint:
 
 
 class TestShardingRules:
-    MESH = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
     def test_divisibility_fallback(self):
         # gemma2: 4 kv heads cannot shard 16 ways -> replicated
@@ -178,7 +169,7 @@ class TestShardingRules:
         assert spec == PartitionSpec(("data", "model"), None, None)
 
     def test_single_pod_mesh_drops_pod_axis(self):
-        mesh = make_abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         spec = spec_for((256, 4096), ("batch", None), TRAIN_RULES, mesh)
         assert spec == PartitionSpec("data", None)
 
