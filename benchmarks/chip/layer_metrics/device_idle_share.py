@@ -1,0 +1,10 @@
+"""Device: the share of the traced window in which no operation ran on
+the chip, from the union of the device's operation intervals, averaged
+over the chips of the cell."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if t is None or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"]), "%"
