@@ -28,7 +28,6 @@ from benchmarks import (
     micro_bandwidth,
     micro_burst,
     micro_failure,
-    obs_overhead,
     perf_transfer,
     reshard,
     roofline,
@@ -44,7 +43,6 @@ MODULES = [
     ("fanout_scheduler", fanout),
     ("swarm_replication", swarm),
     ("failover_control_plane", failover),
-    ("telemetry_overhead", obs_overhead),
     ("fig9_standalone", standalone),
     ("fig11_elastic", elastic),
     ("fig12_cross_dc", cross_dc),

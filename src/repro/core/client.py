@@ -363,7 +363,7 @@ class ShardHandle:
         #: (repro.kernels.repack / quant.fused) instead of the NumPy
         #: reference path
         self.device_repack = device_repack
-        self.store = WorkerStore(worker.worker_id)
+        self.store = WorkerStore(worker.worker_id, client.recorder)
         self.current_version: Optional[int] = None
         #: lifetime count of striped interval reads this handle completed
         #: across all reshard pulls (per-interval progress; the
@@ -609,24 +609,30 @@ class ShardHandle:
         # residuals against this replica's prior version, and the
         # snapshot must already exist when the first delta read arrives
         v = self.current_version
-        if v is not None:
-            self.store.snapshot_base(v)
-        op = self._next_op()
-        with self._cv:
-            res = self._scall(
-                "unpublish", self.model, self.replica, self.shard_idx, op_id=op
-            )
-        if res.offload_required:
-            assert res.offload_version is not None
-            self._do_retention_offload(res.offload_version)
-        self._wait_drained()
-        self.current_version = None
-        self.process_events()
+        rec = self.client.recorder
+        with (
+            rec.span("unpublish", track=self.worker.worker_id, version=v)
+            if rec.enabled
+            else obs.NULL_SPAN
+        ):
+            if v is not None:
+                self.store.snapshot_base(v)
+            op = self._next_op()
+            with self._cv:
+                res = self._scall(
+                    "unpublish", self.model, self.replica, self.shard_idx, op_id=op
+                )
+            if res.offload_required:
+                assert res.offload_version is not None
+                self._do_retention_offload(res.offload_version)
+            self._wait_drained()
+            self.current_version = None
+            self.process_events()
 
     def _do_retention_offload(self, version: int) -> None:
         """Retention protocol (3.3): copy this shard to host memory and
         publish the copy before the GPU buffers may be reused."""
-        off_store = WorkerStore(f"{self.worker.worker_id}@offload")
+        off_store = WorkerStore(f"{self.worker.worker_id}@offload", self.client.recorder)
         self.store.snapshot_to(off_store)
         self._offload_stores[version] = off_store
         self.client.registry.add(offload_name(self.replica), self.shard_idx, off_store)
@@ -1288,14 +1294,17 @@ class ShardHandle:
             "hedged": set(),  # task idxs already duplicated once
             "done_ev": threading.Event(),
         }
+        # each worker thread records its spans on a track of its own, so
+        # spans of threads working at once never overlap on one track
         workers = [
             threading.Thread(
                 target=self._span_worker,
-                args=(sl, shared, dest_name, dest_store, manifest, version),
+                args=(sl, shared, dest_name, dest_store, manifest, version,
+                      f"{self.worker.worker_id}/w{k}"),
                 daemon=True,
                 name=f"{self.worker.worker_id}-pull-{sl.source}",
             )
-            for sl in slices
+            for k, sl in enumerate(slices)
         ]
         for w in workers:
             w.start()
@@ -1447,6 +1456,7 @@ class ShardHandle:
         dest_store: WorkerStore,
         manifest,
         version: int,
+        track: str,
     ) -> None:
         tasks: List[_PullTask] = shared["tasks"]
         claimed: List[bool] = shared["claimed"]
@@ -1527,7 +1537,7 @@ class ShardHandle:
                         self._retry_transient(
                             lambda: self._fetch_task(
                                 pick, tasks[pick], sl, shared, dest_name,
-                                dest_store, manifest, version,
+                                dest_store, manifest, version, track,
                             ),
                             sl.source,
                             unit=tasks[pick].unit,
@@ -1586,6 +1596,7 @@ class ShardHandle:
         dest_store: WorkerStore,
         manifest,
         version: int,
+        track: str,
     ) -> None:
         unit = manifest.units[t.unit]
         if not codec_lib.get_codec(sl.codec).lossless:
@@ -1595,7 +1606,6 @@ class ShardHandle:
                 shared["lossy_units"].add(t.unit)
         whole = t.offset == 0 and t.nbytes == unit.nbytes
         rec = self.client.recorder
-        track = self.worker.worker_id
         lc = _link_class(sl.source, sl.transport)
         started = self.client.clock()
         with shared["lock"]:
@@ -1614,7 +1624,7 @@ class ShardHandle:
             if whole:
                 self.client.transport.pull_unit(
                     sl.source, self.shard_idx, unit, manifest.checksums[t.unit],
-                    dest_store, codec=sl.codec, link_class=lc,
+                    dest_store, codec=sl.codec, link_class=lc, track=track,
                 )
             else:
                 dbase = None
@@ -1627,7 +1637,7 @@ class ShardHandle:
                         dbase = held[t.offset : t.offset + t.nbytes]
                 payload = self.client.transport.read_unit_range(
                     sl.source, self.shard_idx, unit, t.offset, t.nbytes,
-                    codec=sl.codec, link_class=lc, dest_base=dbase,
+                    codec=sl.codec, link_class=lc, dest_base=dbase, track=track,
                 )
         finally:
             if sp is not None:
@@ -1677,11 +1687,10 @@ class ShardHandle:
             # raw (bit-exact) reassembly
             expected = 0 if unit_lossy else manifest.checksums[t.unit]
             if self.client.transport.verify_checksums and expected:
-                t0 = rec.clock() if rec.enabled else 0.0
+                sp = rec.span("verify", track=track, unit=unit.name) if rec.enabled else None
                 got = checksum_lib.checksum(buf)
-                if rec.enabled:
-                    rec.counter_add(obs.CTR_VERIFY, rec.clock() - t0)
-                    rec.event("verify", track=track, unit=unit.name)
+                if sp is not None:
+                    rec.counter_add(obs.CTR_VERIFY, sp.end())
                 if got != expected:
                     n_chunks = -(-unit.nbytes // (self.chunk_bytes or unit.nbytes))
                     raise ChecksumError(
@@ -1706,7 +1715,7 @@ class ShardHandle:
                         dest_store.serving_prefix = max(sp_cur, new_done)
         if advanced:
             if rec.enabled:
-                rec.event("prefix_advance", track=track, done=new_done)
+                rec.event("prefix_advance", track=self.worker.worker_id, done=new_done)
             with self._cv:
                 self._scall(
                     "update_progress",
@@ -1759,24 +1768,27 @@ class ShardHandle:
             s: self._wait_src_manifest(version, assignment.source, shard_idx=s)
             for s in range(src_n)
         }
-        src_layout = layout_from_manifests(src_manifests, src_n)
-        dst_layout = layout_from_manifests(
-            {self.shard_idx: local_manifest}, self.num_shards
-        )
-        plan = plan_shard(
-            src_layout,
-            dst_layout,
-            self.shard_idx,
-            num_dest_units=local_manifest.num_units,
-            codec=codec,
-        )
-        executor = ReshardExecutor(
-            plan, local_manifest, use_kernel=self.device_repack,
-            recorder=self.client.recorder,
-        )
-        source = assignment.source
         rec = self.client.recorder
         track = self.worker.worker_id
+        with rec.span("plan_shard", track=track) if rec.enabled else obs.NULL_SPAN as sp:
+            src_layout = layout_from_manifests(src_manifests, src_n)
+            dst_layout = layout_from_manifests(
+                {self.shard_idx: local_manifest}, self.num_shards
+            )
+            plan = plan_shard(
+                src_layout,
+                dst_layout,
+                self.shard_idx,
+                num_dest_units=local_manifest.num_units,
+                codec=codec,
+            )
+            executor = ReshardExecutor(
+                plan, local_manifest, use_kernel=self.device_repack,
+                recorder=rec,
+            )
+            if rec.enabled:
+                sp.set(intervals=len(plan.intervals))
+        source = assignment.source
         lc = _link_class(source, assignment.transport)
         policy = self.client.retry_policy
         if rejects is None:
@@ -1811,7 +1823,17 @@ class ShardHandle:
             """Kick off window-parallel interval reads for one
             destination unit; returns a ``join()`` that blocks and
             yields payloads in plan order (or re-raises the first
-            worker failure)."""
+            worker failure), and the unit's ``fetch_unit`` span (open
+            on this thread until ``join()`` returns; ``None`` with the
+            recorder off)."""
+            sp = (
+                rec.span(
+                    "fetch_unit", track=track, intervals=len(placed),
+                    bytes=sum(p.interval.read_nbytes for p in placed),
+                )
+                if rec.enabled
+                else None
+            )
             results: List[Optional[np.ndarray]] = [None] * len(placed)
             errors: List[BaseException] = []
             cursor = [0]
@@ -1842,68 +1864,80 @@ class ShardHandle:
                 t.start()
 
             def join():
-                for t in threads:
-                    t.join()
+                try:
+                    for t in threads:
+                        t.join()
+                finally:
+                    if sp is not None:
+                        sp.end()
                 if errors:
                     raise errors[0]
                 return results
 
-            return join
+            return join, sp
 
         batches = list(executor.unit_batches(start_unit=done))
-        join = None
-        for j, (unit, placed) in enumerate(batches):
-            if join is None:
-                join = start_fetch(placed)
-            try:
-                payloads = join()
-            except TransportError as e:
-                raise _SourceLost(
-                    source,
-                    evidence="transient"
-                    if getattr(e, "transient", False)
-                    else "fatal",
-                )
-            except (ChecksumError, codec_lib.CodecError):
-                # corrupt interval from this source: same healing as the
-                # unit pipe — report the evidence, bounded per dest unit
-                rejects[unit.index] = rejects.get(unit.index, 0) + 1
-                if rejects[unit.index] > policy.retry_limit:
-                    raise
-                if rec.enabled:
-                    rec.counter_add(obs.CTR_CORRUPT_REJECTS, 1)
-                    rec.event(
-                        "corrupt_reject", track=track, source=source,
-                        unit=unit.name,
+        join = fetch_sp = None
+        try:
+            for j, (unit, placed) in enumerate(batches):
+                if join is None:
+                    join, fetch_sp = start_fetch(placed)
+                try:
+                    payloads = join()
+                except TransportError as e:
+                    raise _SourceLost(
+                        source,
+                        evidence="transient"
+                        if getattr(e, "transient", False)
+                        else "fatal",
                     )
-                raise _SourceLost(source, evidence="corrupt")
-            join = None
-            if j + 1 < len(batches):
-                # overlap: the next unit's reads fly while this unit
-                # decodes + repacks (the windowed-flow analogue for the
-                # interval plane)
-                join = start_fetch(batches[j + 1][1])
-            t0 = rec.clock() if rec.enabled else 0.0
-            if fused:
-                payload = executor.fused_repack(unit.index, payloads)
-            else:
-                staging = executor.make_staging(unit.index)
-                for p, pay in zip(placed, payloads):
-                    iv = p.interval
-                    staging[
-                        p.staging_offset : p.staging_offset + iv.nbytes
-                    ] = pay[iv.lead : iv.lead + iv.nbytes]
-                payload = executor.repack(unit.index, staging)
-            if rec.enabled:
-                rec.counter_add(obs.CTR_DECODE, rec.clock() - t0)
-            dest_store.write_unit(unit, payload)
-            done += 1
-            dest_store.serving_prefix = done  # before the server learns
-            with self._cv:
-                self._scall(
-                    "update_progress",
-                    self.model, dest_name, self.shard_idx, version, done,
-                )
+                except (ChecksumError, codec_lib.CodecError):
+                    # corrupt interval from this source: same healing as the
+                    # unit pipe — report the evidence, bounded per dest unit
+                    rejects[unit.index] = rejects.get(unit.index, 0) + 1
+                    if rejects[unit.index] > policy.retry_limit:
+                        raise
+                    if rec.enabled:
+                        rec.counter_add(obs.CTR_CORRUPT_REJECTS, 1)
+                        rec.event(
+                            "corrupt_reject", track=track, source=source,
+                            unit=unit.name,
+                        )
+                    raise _SourceLost(source, evidence="corrupt")
+                join = None
+                if j + 1 < len(batches):
+                    # overlap: the next unit's reads fly while this unit
+                    # decodes + repacks (the windowed-flow analogue for the
+                    # interval plane)
+                    join, fetch_sp = start_fetch(batches[j + 1][1])
+                t0 = rec.clock() if rec.enabled else 0.0
+                with rec.span("repack", track=track, unit=unit.name) if rec.enabled else obs.NULL_SPAN:
+                    if fused:
+                        payload = executor.fused_repack(unit.index, payloads)
+                    else:
+                        staging = executor.make_staging(unit.index)
+                        for p, pay in zip(placed, payloads):
+                            iv = p.interval
+                            staging[
+                                p.staging_offset : p.staging_offset + iv.nbytes
+                            ] = pay[iv.lead : iv.lead + iv.nbytes]
+                        payload = executor.repack(unit.index, staging)
+                if rec.enabled:
+                    rec.counter_add(obs.CTR_DECODE, rec.clock() - t0)
+                with rec.span("write", track=track, unit=unit.name) if rec.enabled else obs.NULL_SPAN:
+                    dest_store.write_unit(unit, payload)
+                done += 1
+                dest_store.serving_prefix = done  # before the server learns
+                with self._cv:
+                    self._scall(
+                        "update_progress",
+                        self.model, dest_name, self.shard_idx, version, done,
+                    )
+        finally:
+            # a fetch started and never joined (an error in between) ends
+            # its span here, on the thread that opened it
+            if join is not None and fetch_sp is not None:
+                fetch_sp.end()
         return done
 
     def _await_source_progress(
@@ -1994,7 +2028,7 @@ class ShardHandle:
         # the twin can be fed by a cross-layout source and later consumed
         # locally over PCIe without any further conversion
         buffers = {n: np.zeros_like(a) for n, a in self.store.tensors().items()}
-        off_store = WorkerStore(f"{self.worker.worker_id}@seed")
+        off_store = WorkerStore(f"{self.worker.worker_id}@seed", self.client.recorder)
         off_store.register(buffers, layout=self.store.layouts)
         self._offload_stores[version] = off_store
         self.client.registry.add(twin, self.shard_idx, off_store)

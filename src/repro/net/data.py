@@ -370,9 +370,10 @@ class RemoteTransport(LocalTransport):
             return payload, csum
         raise AssertionError("unreachable")  # pragma: no cover
 
-    @staticmethod
-    def _verify(payload: np.ndarray, expected: int, what: str) -> None:
-        got = checksum_lib.checksum(payload)
+    def _verify(
+        self, payload: np.ndarray, expected: int, what: str, track: Optional[str]
+    ) -> None:
+        got = self._checksum(payload, track)
         if got != expected:
             raise ChecksumError(
                 f"{what}: checksum {got:#x} != expected {expected:#x}"
@@ -397,6 +398,8 @@ class RemoteTransport(LocalTransport):
                 dst_store, codec, link_class, track,
             )
             return
+        if track is None:
+            track = dst_store.worker_id
         self._fault_read(src_replica, shard_idx)
         if self.throttle_s:
             time.sleep(self.throttle_s)
@@ -411,6 +414,7 @@ class RemoteTransport(LocalTransport):
                 self._verify(
                     payload, expected_checksum,
                     f"unit {unit.name} from {src_replica}/shard{shard_idx}",
+                    track,
                 )
             dst_store.write_unit(unit, payload)
             self._account(link_class, unit.nbytes, unit.nbytes)
@@ -437,6 +441,7 @@ class RemoteTransport(LocalTransport):
             self._verify(
                 payload, src_csum,
                 f"unit {unit.name} ({codec}) from {src_replica}/shard{shard_idx}",
+                track,
             )
         dst_store.write_unit(unit, payload)
         self._account(link_class, wire_nbytes, unit.nbytes)
@@ -452,11 +457,12 @@ class RemoteTransport(LocalTransport):
         link_class: str = "rdma",
         dest_base: Optional[np.ndarray] = None,
         decode: bool = True,
+        track: Optional[str] = None,
     ) -> np.ndarray:
         if self._is_local(src_replica, shard_idx):
             return super().read_unit_range(
                 src_replica, shard_idx, unit, offset, nbytes,
-                codec, link_class, dest_base, decode,
+                codec, link_class, dest_base, decode, track,
             )
         self._fault_read(src_replica, shard_idx)
         if self.throttle_s:
@@ -481,6 +487,7 @@ class RemoteTransport(LocalTransport):
                     payload, src_csum,
                     f"chunk {unit.name}[{offset}:{offset + nbytes}] "
                     f"({codec} wire) from {src_replica}/shard{shard_idx}",
+                    track,
                 )
             self._account(link_class, payload.nbytes, nbytes)
             return payload
@@ -492,6 +499,7 @@ class RemoteTransport(LocalTransport):
                     payload, src_csum,
                     f"chunk {unit.name}[{offset}:{offset + nbytes}] from "
                     f"{src_replica}/shard{shard_idx}",
+                    track,
                 )
             self._account(link_class, nbytes, nbytes)
             return payload
@@ -517,6 +525,7 @@ class RemoteTransport(LocalTransport):
                 payload, src_csum,
                 f"chunk {unit.name}[{offset}:{offset + nbytes}] ({codec}) from "
                 f"{src_replica}/shard{shard_idx}",
+                track,
             )
         self._account(link_class, wire_nbytes, nbytes)
         return payload

@@ -1,9 +1,11 @@
 """Transfer telemetry plane (observability).
 
-``telemetry`` — the low-overhead recorder (spans / counters /
-histograms), clock-injected so the same instrumentation runs under
+``telemetry`` — the low-overhead recorder (spans / instant events /
+counters), clock-injected so the same instrumentation runs under
 ``time.monotonic`` (threaded data plane) and ``SimEnv`` virtual time
-(simulator). ``export`` — Chrome trace-event JSON (Perfetto-viewable)
+(simulator); on the wall clock its spans also reach the JAX profiler's
+trace as ``tensorhub.<name>``, and ``wall_seconds`` reads the wall time
+a set of spans covers. ``export`` — Chrome trace-event JSON (Perfetto-viewable)
 and a textual timeline renderer.
 """
 
@@ -12,6 +14,7 @@ from repro.obs.telemetry import (
     STALL_COMPONENTS,
     Recorder,
     stall_breakdown,
+    wall_seconds,
 )
 from repro.obs.export import (
     chrome_trace_events,
@@ -28,5 +31,6 @@ __all__ = [
     "chrome_trace_events",
     "render_timeline",
     "stall_breakdown",
+    "wall_seconds",
     "write_chrome_trace",
 ]

@@ -71,8 +71,11 @@ class WorkerStore:
     one-sided RDMA semantics.
     """
 
-    def __init__(self, worker_id: str) -> None:
+    def __init__(self, worker_id: str, recorder: Optional[obs.Recorder] = None) -> None:
         self.worker_id = worker_id
+        #: telemetry: ``snapshot_base`` and a checksummed
+        #: ``build_manifest`` each open a span on the track ``worker_id``
+        self.recorder = obs.DISABLED if recorder is None else recorder
         self._lock = threading.Lock()
         self._buffers: Dict[str, np.ndarray] = {}
         self._layouts: Dict[str, LayoutEntry] = {}
@@ -192,10 +195,16 @@ class WorkerStore:
     def build_manifest(self, *, with_checksums: bool = True) -> ShardManifest:
         if not self._buffers:
             raise NotRegisteredError(f"{self.worker_id}: no tensors registered")
-        sums = tuple(
-            checksum_lib.checksum(self._gather_unit(u)) if with_checksums else 0
-            for u in self._units
-        )
+        rec = self.recorder
+        with (
+            rec.span("manifest", track=self.worker_id, bytes=self.total_bytes)
+            if with_checksums and rec.enabled
+            else obs.NULL_SPAN
+        ):
+            sums = tuple(
+                checksum_lib.checksum(self._gather_unit(u)) if with_checksums else 0
+                for u in self._units
+            )
         return ShardManifest(
             tensors=tuple(self._metas), units=tuple(self._units), checksums=sums
         )
@@ -297,7 +306,12 @@ class WorkerStore:
         ``delta:<base>`` transfer encode/decode against these bytes.
         Only the most recent snapshot is kept (one version of history,
         matching the server's prior-version bookkeeping)."""
-        with self._lock:
+        rec = self.recorder
+        with (
+            rec.span("snapshot_base", track=self.worker_id, bytes=self.total_bytes)
+            if rec.enabled
+            else obs.NULL_SPAN
+        ), self._lock:
             self._base_version = version
             self._base_units = {
                 u.name: self._gather_unit(u).copy() for u in self._units
@@ -441,6 +455,21 @@ class LocalTransport:
         except (TensorHubError, KeyError):
             return None
 
+    def _checksum(self, buf: np.ndarray, track: Optional[str]) -> int:
+        """The checksum of ``buf``, its time added to ``stall/verify``; a
+        ``verify`` span on ``track`` where one is given (the resharded
+        path's interval reads give none: ~115k an update)."""
+        rec = self.recorder
+        if not rec.enabled:
+            return checksum_lib.checksum(buf)
+        sp = rec.span("verify", track=track) if track is not None else None
+        t0 = rec.clock()
+        got = checksum_lib.checksum(buf)
+        rec.counter_add(obs.CTR_VERIFY, rec.clock() - t0)
+        if sp is not None:
+            sp.end()
+        return got
+
     def _account(self, link_class: str, wire_nbytes: int, decoded_nbytes: int) -> None:
         # windowed pulls share one transport across span-worker threads
         with self._acct_lock:
@@ -471,34 +500,62 @@ class LocalTransport:
         time (a lossy codec's output cannot match the publish-time sum)
         and the reader re-verifies after the wire copy, the same transit
         contract as :meth:`read_unit_range`. ``bytes_moved`` counts wire
-        bytes, i.e. what the NIC actually carried."""
+        bytes, i.e. what the NIC actually carried.
+
+        Spans go on ``track`` (the destination store's ``worker_id`` when
+        none is given): ``wire_copy`` and ``write`` on the raw wire,
+        ``decode`` on a coded one, ``verify`` around every checksum."""
         src = self.registry.get(src_replica, shard_idx)
         self._fault_read(src_replica, shard_idx)
         cdc = codec_lib.get_codec(codec)
         rec = self.recorder
+        if track is None:
+            track = dst_store.worker_id
         if codec == "raw":
-            payload = src.read_unit(unit).copy()  # the wire copy
+            with rec.span("wire_copy", track=track) if rec.enabled else obs.NULL_SPAN:
+                payload = src.read_unit(unit).copy()  # the wire copy
             self._fault_flip(
                 src_replica,
                 payload,
                 self.verify_checksums and bool(expected_checksum),
             )
             if self.verify_checksums and expected_checksum:
-                t0 = rec.clock() if rec.enabled else 0.0
-                got = checksum_lib.checksum(payload)
-                if rec.enabled:
-                    rec.counter_add(obs.CTR_VERIFY, rec.clock() - t0)
-                    if track is not None:
-                        rec.event("verify", track=track, unit=unit.name)
+                got = self._checksum(payload, track)
                 if got != expected_checksum:
                     raise ChecksumError(
                         f"unit {unit.name} from {src_replica}/shard{shard_idx}: "
                         f"checksum {got:#x} != expected {expected_checksum:#x}"
                     )
-            dst_store.write_unit(unit, payload)
+            with rec.span("write", track=track) if rec.enabled else obs.NULL_SPAN:
+                dst_store.write_unit(unit, payload)
             self._account(link_class, unit.nbytes, unit.nbytes)
             return
-        t0 = rec.clock() if rec.enabled else 0.0
+        sp = rec.span("decode", track=track, unit=unit.name, codec=codec) if rec.enabled else None
+        try:
+            wire_nbytes, decoded_src = self._encode_decode(
+                src_replica, src, unit, dst_store, cdc, codec, track
+            )
+        finally:
+            if sp is not None:
+                rec.counter_add(obs.CTR_DECODE, sp.end())
+        expected = self._checksum(decoded_src, track) if self.verify_checksums else 0
+        payload = decoded_src.copy()  # the wire copy, decoded at the dest
+        self._fault_flip(src_replica, payload, self.verify_checksums)
+        if self.verify_checksums:
+            got = self._checksum(payload, track)
+            if got != expected:
+                raise ChecksumError(
+                    f"unit {unit.name} ({codec}) from "
+                    f"{src_replica}/shard{shard_idx}: decoded checksum "
+                    f"{got:#x} != expected {expected:#x}"
+                )
+        dst_store.write_unit(unit, payload)
+        self._account(link_class, wire_nbytes, unit.nbytes)
+
+    def _encode_decode(self, src_replica, src, unit, dst_store, cdc, codec, track):
+        """A coded unit read: the source's encode and the destination's
+        decode; returns ``(wire bytes, decoded payload)``."""
+        rec = self.recorder
         raw_payload = src.read_unit(unit)
         dtype = src.unit_dtype(unit)
         if getattr(cdc, "needs_base", False):
@@ -519,13 +576,12 @@ class LocalTransport:
                     self.delta_stale_fallbacks += 1
                 if rec.enabled:
                     rec.counter_add(obs.CTR_DELTA_STALE, 1)
-                    if track is not None:
-                        rec.event(
-                            "delta_stale_fallback",
-                            track=track,
-                            unit=unit.name,
-                            codec=codec,
-                        )
+                    rec.event(
+                        "delta_stale_fallback",
+                        track=track,
+                        unit=unit.name,
+                        codec=codec,
+                    )
                 wire = self._fault_truncate(
                     src_replica, cdc.encode(raw_payload, dtype)
                 )
@@ -542,33 +598,7 @@ class LocalTransport:
             # still runs over two distinct buffers, without paying a
             # second dequantize
             decoded_src = cdc.decode(wire)
-        if rec.enabled:
-            rec.counter_add(obs.CTR_DECODE, rec.clock() - t0)
-            if track is not None:
-                rec.event("decode", track=track, unit=unit.name, codec=codec,
-                          wire_bytes=wire_nbytes)
-        t0 = rec.clock() if rec.enabled else 0.0
-        expected = (
-            checksum_lib.checksum(decoded_src) if self.verify_checksums else 0
-        )
-        t_verify = (rec.clock() - t0) if rec.enabled else 0.0
-        payload = decoded_src.copy()  # the wire copy, decoded at the dest
-        self._fault_flip(src_replica, payload, self.verify_checksums)
-        if self.verify_checksums:
-            t0 = rec.clock() if rec.enabled else 0.0
-            got = checksum_lib.checksum(payload)
-            if rec.enabled:
-                rec.counter_add(obs.CTR_VERIFY, t_verify + (rec.clock() - t0))
-                if track is not None:
-                    rec.event("verify", track=track, unit=unit.name)
-            if got != expected:
-                raise ChecksumError(
-                    f"unit {unit.name} ({codec}) from "
-                    f"{src_replica}/shard{shard_idx}: decoded checksum "
-                    f"{got:#x} != expected {expected:#x}"
-                )
-        dst_store.write_unit(unit, payload)
-        self._account(link_class, wire_nbytes, unit.nbytes)
+        return wire_nbytes, decoded_src
 
     def read_unit_range(
         self,
@@ -581,6 +611,7 @@ class LocalTransport:
         link_class: str = "rdma",
         dest_base: Optional[np.ndarray] = None,
         decode: bool = True,
+        track: Optional[str] = None,
     ) -> np.ndarray:
         """Pull one byte sub-range of a transfer unit (sub-unit chunking,
         and — since the row-grid reshard planner — every resharded
@@ -619,7 +650,11 @@ class LocalTransport:
         ``read_unit`` below refuses units past the source's watermark, so
         a chunk of a not-yet-final unit can never be served (chunk-level
         checksums alone would not catch it — they are computed at read
-        time and would happily cover garbage)."""
+        time and would happily cover garbage).
+
+        With a ``track``, each checksum opens a ``verify`` span and a
+        decode a ``decode`` span there; without one (the resharded path's
+        interval reads) only the counters count them."""
         src = self.registry.get(src_replica, shard_idx)
         self._fault_read(src_replica, shard_idx)
         full = src.read_unit(unit)
@@ -634,16 +669,11 @@ class LocalTransport:
         view = full[offset : offset + nbytes]
         rec = self.recorder
         if codec == "raw":
-            t0 = rec.clock() if rec.enabled else 0.0
-            expected = checksum_lib.checksum(view) if self.verify_checksums else 0
-            t_verify = (rec.clock() - t0) if rec.enabled else 0.0
+            expected = self._checksum(view, track) if self.verify_checksums else 0
             payload = view.copy()  # the wire copy
             self._fault_flip(src_replica, payload, self.verify_checksums)
             if self.verify_checksums:
-                t0 = rec.clock() if rec.enabled else 0.0
-                got = checksum_lib.checksum(payload)
-                if rec.enabled:
-                    rec.counter_add(obs.CTR_VERIFY, t_verify + (rec.clock() - t0))
+                got = self._checksum(payload, track)
                 if got != expected:
                     raise ChecksumError(
                         f"chunk {unit.name}[{offset}:{offset + nbytes}] from "
@@ -672,20 +702,11 @@ class LocalTransport:
             wire = self._fault_truncate(src_replica, cdc.encode(view, dtype))
             if rec.enabled:
                 rec.counter_add(obs.CTR_DECODE, rec.clock() - t0)
-            t0 = rec.clock() if rec.enabled else 0.0
-            expected = (
-                checksum_lib.checksum(wire) if self.verify_checksums else 0
-            )
-            t_verify = (rec.clock() - t0) if rec.enabled else 0.0
+            expected = self._checksum(wire, track) if self.verify_checksums else 0
             payload = wire.copy()  # the wire copy, decoded by the caller
             self._fault_flip(src_replica, payload, self.verify_checksums)
             if self.verify_checksums:
-                t0 = rec.clock() if rec.enabled else 0.0
-                got = checksum_lib.checksum(payload)
-                if rec.enabled:
-                    rec.counter_add(
-                        obs.CTR_VERIFY, t_verify + (rec.clock() - t0)
-                    )
+                got = self._checksum(payload, track)
                 if got != expected:
                     raise ChecksumError(
                         f"chunk {unit.name}[{offset}:{offset + nbytes}] "
@@ -694,7 +715,41 @@ class LocalTransport:
                     )
             self._account(link_class, payload.nbytes, nbytes)
             return payload
+        sp = (
+            rec.span("decode", track=track, unit=unit.name, codec=codec)
+            if rec.enabled and track is not None
+            else None
+        )
         t0 = rec.clock() if rec.enabled else 0.0
+        try:
+            wire_nbytes, decoded_src = self._encode_decode_range(
+                src_replica, src, unit, view, offset, nbytes, cdc, dtype, dest_base
+            )
+        finally:
+            if rec.enabled:
+                rec.counter_add(obs.CTR_DECODE, rec.clock() - t0)
+            if sp is not None:
+                sp.end()
+        expected = self._checksum(decoded_src, track) if self.verify_checksums else 0
+        payload = decoded_src.copy()  # the wire copy, decoded at the dest
+        self._fault_flip(src_replica, payload, self.verify_checksums)
+        if self.verify_checksums:
+            got = self._checksum(payload, track)
+            if got != expected:
+                raise ChecksumError(
+                    f"chunk {unit.name}[{offset}:{offset + nbytes}] ({codec}) "
+                    f"from {src_replica}/shard{shard_idx}: decoded checksum "
+                    f"{got:#x} != expected {expected:#x}"
+                )
+        self._account(link_class, wire_nbytes, nbytes)
+        return payload
+
+    def _encode_decode_range(
+        self, src_replica, src, unit, view, offset, nbytes, cdc, dtype, dest_base
+    ):
+        """A coded range read: the source's encode and the destination's
+        decode; returns ``(wire bytes, decoded payload)``."""
+        rec = self.recorder
         if getattr(cdc, "needs_base", False):
             base_full = src.base_unit(unit)
             base_view = (
@@ -720,26 +775,5 @@ class LocalTransport:
             # single decode (see pull_unit): checksum the decoded bytes at
             # the source, copy models the wire + destination decode
             decoded_src = cdc.decode(wire)
-        if rec.enabled:
-            rec.counter_add(obs.CTR_DECODE, rec.clock() - t0)
-        t0 = rec.clock() if rec.enabled else 0.0
-        expected = (
-            checksum_lib.checksum(decoded_src) if self.verify_checksums else 0
-        )
-        t_verify = (rec.clock() - t0) if rec.enabled else 0.0
-        payload = decoded_src.copy()  # the wire copy, decoded at the dest
-        self._fault_flip(src_replica, payload, self.verify_checksums)
-        if self.verify_checksums:
-            t0 = rec.clock() if rec.enabled else 0.0
-            got = checksum_lib.checksum(payload)
-            if rec.enabled:
-                rec.counter_add(obs.CTR_VERIFY, t_verify + (rec.clock() - t0))
-            if got != expected:
-                raise ChecksumError(
-                    f"chunk {unit.name}[{offset}:{offset + nbytes}] ({codec}) "
-                    f"from {src_replica}/shard{shard_idx}: decoded checksum "
-                    f"{got:#x} != expected {expected:#x}"
-                )
-        self._account(link_class, wire_nbytes, nbytes)
-        return payload
+        return wire_nbytes, decoded_src
 

@@ -1,9 +1,12 @@
 """Telemetry plane tests: recorder semantics, virtual-clock fidelity,
-disabled-path cost, Chrome trace export, per-link-class byte counters,
+disabled-path cost, the bridge onto the JAX profiler's clock, the spans
+of each pull stage, Chrome trace export, per-link-class byte counters,
 stall decomposition on both data planes, and server metrics consistency
 across crash/replay."""
 
+import glob
 import json
+import threading
 import tracemalloc
 
 import numpy as np
@@ -20,7 +23,9 @@ from repro.obs import (
     stall_breakdown,
     write_chrome_trace,
 )
-from repro.obs.telemetry import NULL_SPAN
+from repro.obs import telemetry
+from repro.obs.telemetry import NULL_SPAN, wall_seconds
+from repro.resharding import tp_shard
 from repro.transfer.simcluster import SimCluster
 from repro.transfer.simnet import SimEnv
 
@@ -61,10 +66,7 @@ class TestRecorder:
         rec.counter_add("c", 2.0)
         rec.counter_add("c", 3.0)
         assert rec.counter("c") == 5.0
-        for v in (3.0, 1.0, 2.0):
-            rec.observe("h", v)
-        s = rec.histogram_summary("h")
-        assert (s["count"], s["min"], s["p50"], s["max"]) == (3, 1.0, 2.0, 3.0)
+        assert rec.counter("never") == 0.0
 
     def test_virtual_clock_spans_match_simenv_exactly(self):
         env = SimEnv()
@@ -103,13 +105,11 @@ class TestRecorder:
         for _ in range(3):
             rec.counter_add("c", 1.0)
             rec.event("e")
-            rec.observe("h", 1.0)
         tracemalloc.start()
         before = tracemalloc.take_snapshot()
         for _ in range(100):
             rec.counter_add("c", 1.0)
             rec.event("e")
-            rec.observe("h", 1.0)
             sp = rec.span("x")
             sp.end()
         after = tracemalloc.take_snapshot()
@@ -120,7 +120,255 @@ class TestRecorder:
             and d.size_diff > 0
         ]
         assert not grown, grown
-        assert rec.events == [] and rec.counters == {} and rec.histograms == {}
+        assert rec.events == [] and rec.counters == {}
+
+    def test_threaded_spans_get_their_own_threads_parent(self):
+        rec = Recorder()
+        opened = threading.Barrier(2)
+        done = threading.Barrier(2)
+
+        def work(k):
+            with rec.span(f"outer{k}", track="t"):
+                opened.wait()  # both outers open at once on one track
+                with rec.span(f"inner{k}", track="t"):
+                    pass
+                done.wait()
+
+        threads = [threading.Thread(target=work, args=(k,)) for k in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        parents = {e[0]: e[4] for e in rec.events}
+        assert parents == {"inner0": "outer0", "inner1": "outer1",
+                           "outer0": None, "outer1": None}
+        assert rec._open == {}  # finished stacks are dropped
+
+    def test_wall_seconds_counts_overlap_once(self):
+        events = [
+            ("verify", "a/w0", 1.0, 3.0, None, None),
+            ("verify", "a/w1", 2.0, 4.0, None, None),  # overlaps the first
+            ("verify", "a/w0", 6.0, 7.0, None, None),
+            ("write", "a/w0", 3.0, 6.0, None, None),  # another name
+            ("verify", "a/w0", 9.0, 12.0, None, None),  # clipped at hi
+        ]
+        assert wall_seconds(events, ["verify"], 0.0, 10.0) == pytest.approx(5.0)
+        assert wall_seconds(events, ["verify", "write"], 0.0, 10.0) == pytest.approx(7.0)
+        assert wall_seconds(events, ["verify"], 2.5, 6.5) == pytest.approx(2.0)
+        assert wall_seconds(events, ["absent"], 0.0, 10.0) == 0.0
+
+
+def _weights(seed=0, n=3, elems=1 << 20):
+    # random payloads: a constant fill would fold to a trivial checksum
+    rng = np.random.RandomState(seed)
+    return {f"w{i}": rng.randn(elems).astype(np.float32) for i in range(n)}
+
+
+def _raw_update(rec, **client_kw):
+    """A publisher and a reader in one DC: v0 published and replicated,
+    then unpublish, v1 published and pulled by ``update``; returns the
+    reader's handle."""
+    hub = TensorHubClient(ReferenceServer(), recorder=rec, **client_kw)
+    w = _weights()
+    pub = hub.open("m", "pub", 1, 0)
+    pub.register(w)
+    pub.publish(0)
+    r = hub.open("m", "r", 1, 0)
+    r.register({k: np.zeros_like(v) for k, v in w.items()})
+    r.replicate(0)
+    pub.unpublish()
+    for v in w.values():
+        v += 1.0
+    pub.publish(1)
+    assert r.update("latest")
+    return pub, r
+
+
+def _reshard_replicate(rec, src_tp=2):
+    """A TP-``src_tp`` publisher resharded into one TP-1 reader (raw)."""
+    rng = np.random.default_rng(0)
+    glob_w = {
+        "w0": rng.standard_normal((512, 8)).astype(np.float32),
+        "w1": rng.standard_normal((256, 4)).astype(np.float32),
+    }
+    hub = TensorHubClient(ReferenceServer(), recorder=rec)
+    pubs = [hub.open("m", "pub", src_tp, i) for i in range(src_tp)]
+    for h in pubs:
+        local, lay = tp_shard(glob_w, h.shard_idx, src_tp)
+        h.register(local, layout=lay)
+    threads = [threading.Thread(target=h.publish, args=(0,)) for h in pubs]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    r = hub.open("m", "r", 1, 0)
+    local, lay = tp_shard(glob_w, 0, 1)
+    r.register({n: np.zeros_like(a) for n, a in local.items()}, layout=lay)
+    r.replicate(0)
+    for n, a in glob_w.items():
+        np.testing.assert_array_equal(r.store.get(n), a)
+    return r
+
+
+class TestPullStageSpans:
+    def test_windowed_raw_pull_spans_every_unit(self):
+        rec = Recorder()
+        pub, r = _raw_update(rec)  # default client: window=4
+        units = len(r.store.units)
+        assert units >= 2
+        upd = [e for e in rec.events if e[0] == "update"]
+        assert len(upd) == 1 and upd[0][5] == {"version": 1}
+        lo, hi = upd[0][2], upd[0][3]
+        inside = [e for e in rec.events if lo <= e[2] and e[3] <= hi]
+        for name in ("pull_unit", "wire_copy", "verify", "write"):
+            mine = [e for e in inside if e[0] == name]
+            assert len(mine) == units, name
+            # the windowed executor's thread records on a track of its own
+            assert {e[1] for e in mine} == {"r/shard0/w0"}, name
+        for name in ("wire_copy", "verify", "write"):
+            assert {e[4] for e in inside if e[0] == name} == {"pull_unit"}
+        assert sorted(e[5]["unit"] for e in inside if e[0] == "pull_unit") == sorted(
+            u.name for u in r.store.units
+        )
+        # the old instant verify events are gone: every verify has a length
+        assert all(e[3] > e[2] for e in inside if e[0] == "verify")
+
+    def test_update_stages_span_publisher_and_reader(self):
+        rec = Recorder()
+        pub, r = _raw_update(rec, window=1, chunk_bytes=None)
+        by = {}
+        for e in rec.events:
+            by.setdefault(e[0], []).append(e)
+        (unpub,) = by["unpublish"]
+        assert unpub[1] == "pub/shard0" and unpub[5] == {"version": 0}
+        total = pub.store.total_bytes
+        # the publisher snapshots at unpublish, the reader before its pull
+        snaps = {e[1]: e for e in by["snapshot_base"]}
+        assert set(snaps) == {"pub/shard0", "r/shard0"}
+        assert snaps["pub/shard0"][4] == "unpublish"
+        assert snaps["r/shard0"][4] == "update"
+        assert all(e[5] == {"bytes": total} for e in snaps.values())
+        # one checksummed manifest per publish; unchecksummed ones record nothing
+        mans = by["manifest"]
+        assert [e[1] for e in mans] == ["pub/shard0", "pub/shard0"]
+        assert [e[4] for e in mans] == ["publish", "publish"]
+        # the sequential path keeps the handle's track
+        assert {e[1] for e in by["wire_copy"]} == {"r/shard0"}
+
+    def test_reshard_pull_spans_plan_fetch_repack_write(self):
+        rec = Recorder()
+        r = _reshard_replicate(rec)
+        by = {}
+        for e in rec.events:
+            by.setdefault(e[0], []).append(e)
+        (plan,) = by["plan_shard"]
+        assert plan[1] == "r/shard0" and plan[4] == "replicate"
+        units = len(r.store.units)
+        fetches = by["fetch_unit"]
+        assert len(fetches) == len(by["repack"]) == len(by["write"]) == units
+        # per-interval work is counted, not spanned
+        assert sum(e[5]["intervals"] for e in fetches) == plan[5]["intervals"]
+        assert plan[5]["intervals"] == r.intervals_pulled > units
+        assert sum(e[5]["bytes"] for e in fetches) == r.store.total_bytes
+        assert "verify" not in by  # interval checksums: counters only
+        assert rec.counter(telemetry.CTR_VERIFY) > 0
+        # the reshard upgrade re-checksums the pulled shard
+        assert any(e[1] == "r/shard0" for e in by["manifest"])
+
+    def test_disabled_recorder_allocates_nothing_at_new_sites(self):
+        off = Recorder(enabled=False)
+        opened = []
+
+        def span(name, *a, **kw):  # every site checks rec.enabled first
+            opened.append(name)
+            return NULL_SPAN
+
+        off.span = span
+
+        def pulls():
+            _raw_update(off)  # windowed
+            _raw_update(off, window=1, chunk_bytes=None)  # sequential
+            _reshard_replicate(off)
+
+        pulls()  # warm lazy caches on every site first
+        tracemalloc.start(64)
+        before = tracemalloc.take_snapshot()
+        pulls()
+        after = tracemalloc.take_snapshot()
+        tracemalloc.stop()
+        # allocations made through telemetry.py by this test's own call
+        # chain (threads other tests left behind may be recording too)
+        grown = [
+            d for d in after.compare_to(before, "traceback")
+            if d.size_diff > 0
+            and any(f.filename.endswith("telemetry.py") for f in d.traceback)
+            and any(f.filename == __file__ for f in d.traceback)
+        ]
+        assert not grown, grown
+        assert opened == []
+        assert off.events == [] and off.counters == {} and off._open == {}
+
+
+class TestProfilerBridge:
+    def _host_names(self, tmp_path, rec):
+        import jax
+        from jax.profiler import ProfileData
+
+        with jax.profiler.trace(str(tmp_path)):
+            _raw_update(rec)
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        names = []
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    names += [ev.name for ev in line.events if ev.name.startswith("tensorhub.")]
+        return names
+
+    def test_program_spans_reach_the_profilers_host_plane(self, tmp_path):
+        rec = Recorder()
+        names = self._host_names(tmp_path, rec)
+        want = {"tensorhub.update", "tensorhub.snapshot_base", "tensorhub.verify",
+                "tensorhub.manifest", "tensorhub.unpublish", "tensorhub.wire_copy"}
+        assert want <= set(names)
+        # one host-plane span per program span
+        for span in ("replicate", "update", "publish", "unpublish", "snapshot_base",
+                     "manifest", "pull_unit", "wire_copy", "verify", "write"):
+            recorded = sum(1 for e in rec.events if e[0] == span)
+            assert recorded and names.count("tensorhub." + span) == recorded, span
+
+    def test_injected_clock_leaves_no_host_spans(self, tmp_path):
+        import time
+
+        rec = Recorder(clock=lambda: time.monotonic())
+        assert self._host_names(tmp_path, rec) == []
+        assert any(e[0] == "update" for e in rec.events)
+
+    def test_span_must_end_on_its_own_thread(self, tmp_path):
+        import jax
+
+        def end_elsewhere(sp):
+            err = []
+
+            def other():
+                try:
+                    sp.end()
+                except RuntimeError as e:
+                    err.append(e)
+
+            t = threading.Thread(target=other)
+            t.start()
+            t.join()
+            return err
+
+        rec = Recorder()
+        with jax.profiler.trace(str(tmp_path)):
+            sp = rec.span("x", track="t")  # bridged: an annotation is open
+            err = end_elsewhere(sp)
+            assert err and "another thread" in str(err[0])
+            assert sp.end() is not None  # still open; its own thread ends it
+        # with no profiler session nothing is bridged, and nothing to break
+        assert end_elsewhere(rec.span("y")) == []
+        assert [e[0] for e in rec.events] == ["x", "y"]
 
 
 class TestExport:
