@@ -14,12 +14,18 @@ Algorithm (per destination shard, per tensor)
    shard's local buffer and the destination shard's local buffer
    (C-order rows along the last dim, merged when adjacent). Dim-0
    sharding — the common TP case — merges to a single run.
-2. Sweep the destination's local byte space over run boundaries; every
-   elementary segment is assigned to the least-loaded source shard that
-   covers it (load = bytes already assigned to that source shard by this
-   destination shard). Segments covered by several source shards
-   (replicated tensors, overlapping slices) are additionally split into
-   stripes so no single source serializes the read.
+2. Sweep the destination's local byte space over run boundaries in
+   ascending order; every elementary segment is assigned to the
+   least-loaded source shard that covers it (load = bytes already
+   assigned to that source shard by this destination shard). A shard's
+   runs ascend in destination offset and are disjoint, so each shard
+   keeps one cursor: the segment ``[a, b)`` can only lie in the shard's
+   first run that ends beyond ``a``. The cursor moves past runs that end
+   at or before ``a`` and only the run under it is tested, so a tensor
+   costs O(segments x source shards + runs). Segments covered by several
+   source shards (replicated tensors, overlapping slices) are
+   additionally split into stripes so no single source serializes the
+   read.
 3. A segment no source covers means the layouts are not convertible:
    :class:`repro.core.errors.ShardLayoutError`.
 
@@ -165,8 +171,9 @@ def _intersection_runs(
     dest: ShardSlice, src: ShardSlice, itemsize: int
 ) -> List[Tuple[int, int, int]]:
     """Contiguous runs of ``dest ∩ src`` as ``(dst_off, src_off, nbytes)``
-    byte triples, offsets local to each side's buffer. Empty when the
-    slices don't overlap."""
+    byte triples, offsets local to each side's buffer, in ascending
+    ``dst_off`` and disjoint (the coverage sweep's cursors rely on it).
+    Empty when the slices don't overlap."""
     ndim = max(len(dest.shape), 1)
     d_start = dest.start or (0,)
     d_shape = dest.shape or (1,)
@@ -229,6 +236,7 @@ def _plan_tensor(
     wire = get_codec(codec)
     for src_slice in tensor.slices:
         r = _intersection_runs(dest_slice, src_slice, tensor.itemsize)
+        assert all(p[0] + p[2] <= q[0] for p, q in zip(r, r[1:]))
         if r:
             runs[src_slice.shard] = r
             place[src_slice.shard] = src_slice
@@ -265,14 +273,21 @@ def _plan_tensor(
         )
         load[shard] = load.get(shard, 0) + (dst_b - dst_a)
 
+    # per candidate, the index of its first run that may still cover a
+    # segment: runs ascend in dst_off and the edges are swept in order
+    cursor = dict.fromkeys(runs, 0)
     for a, b in zip(edges[:-1], edges[1:]):
         # candidates covering [a, b): (shard, src byte offset at a)
         cands: List[Tuple[int, int]] = []
         for shard, rs in runs.items():
-            for dst_off, src_off, nbytes in rs:
+            i = cursor[shard]
+            while i < len(rs) and rs[i][0] + rs[i][2] <= a:
+                i += 1
+            cursor[shard] = i
+            if i < len(rs):
+                dst_off, src_off, nbytes = rs[i]
                 if dst_off <= a and b <= dst_off + nbytes:
                     cands.append((shard, src_off + (a - dst_off)))
-                    break
         if not cands:
             raise ShardLayoutError(
                 f"tensor {tensor.name!r}: destination bytes [{a}, {b}) of "
